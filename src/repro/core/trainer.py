@@ -291,19 +291,48 @@ def train_enhancers_streamed(
                                  callback=callback)
 
 
+# Pixels per step of the whole-training-set passes below (BN calibration and
+# the gate).  Each step holds a [G, B, H, W, 9, C] neighbourhood tensor, so
+# the pass runs over slice batches and accumulates per-group sums: program
+# size then depends on the slice shape only, never on the reservoir size.
+_PASS_PIXELS = 1 << 16
+
+
+def _slice_batches(*arrays_and_fills):
+    """Split [N, H, W] slice stacks into [nb, B, H, W] scan batches, padding
+    N up to a multiple of B with the given fill (ids pad with ``n_groups``,
+    whose one-hot mask is all zero, so pad slices add nothing to any sum)."""
+    n, h, w = arrays_and_fills[0][0].shape
+    b = max(1, min(n, _PASS_PIXELS // (h * w)))
+    pad = (-n) % b
+    out = []
+    for a, fill in arrays_and_fills:
+        if pad:
+            a = jnp.concatenate([a, jnp.full((pad,) + a.shape[1:], fill, a.dtype)])
+        out.append(a.reshape((-1, b) + a.shape[1:]))
+    return out
+
+
 @partial(jax.jit, static_argnames=("n_groups",))
 def _gate_groups(params, bn_state, xs, rs, ids, edges, rscale, *, n_groups):
     """Per-group acceptance test on the training volume: keep a group's
     enhancer only if it reduces that group's residual MSE."""
-    xn, masks = _group_inputs(xs, ids, edges, n_groups)
 
     def one(p, st, xg):
         pred, _ = enhancer.apply(p, st, xg, train=False)
         return pred
 
-    preds = jax.vmap(one)(params, bn_state, xn) * rscale[:, None, None, None]
-    err_with = (((rs[None] - preds) * masks) ** 2).sum(axis=(1, 2, 3))
-    err_without = ((rs[None] * masks) ** 2).sum(axis=(1, 2, 3))
+    def step(acc, batch):
+        xb, rb, ib = batch
+        xn, masks = _group_inputs(xb, ib, edges, n_groups)
+        preds = jax.vmap(one)(params, bn_state, xn) * rscale[:, None, None, None]
+        err_with = (((rb[None] - preds) * masks) ** 2).sum(axis=(1, 2, 3))
+        err_without = ((rb[None] * masks) ** 2).sum(axis=(1, 2, 3))
+        return (acc[0] + err_with, acc[1] + err_without), None
+
+    zero = jnp.zeros((n_groups,), jnp.float32)
+    batches = _slice_batches((xs, 0.0), (rs, 0.0), (ids, n_groups))
+    (err_with, err_without), _ = jax.lax.scan(step, (zero, zero), tuple(batches))
     return (err_with < err_without).astype(jnp.float32)
 
 
@@ -313,18 +342,35 @@ def _bn_calibrate(params, xs, ids, edges, *, n_groups):
 
     Per-batch BN statistics drift from the running average enough to cost
     ~1 dB at inference; since compression trains on exactly the data it will
-    enhance, we can use the exact statistics (one extra forward pass)."""
-    xn, masks = _group_inputs(xs, ids, edges, n_groups)
+    enhance, we can use the exact statistics (one extra forward pass).  Two
+    passes over slice batches: per-group masked sums give the mean, then the
+    masked squared deviations from it give the variance."""
+    batches = tuple(_slice_batches((xs, 0.0), (ids, n_groups)))
 
-    def stats_one(p, xg, maskg):
-        h = enhancer._conv(xg[..., None], p["w1"], p["b1"])
-        m = maskg[..., None]
-        cnt = jnp.maximum(m.sum(axis=(0, 1, 2)), 1.0)
-        mean = (h * m).sum(axis=(0, 1, 2)) / cnt
-        var = ((h - mean) ** 2 * m).sum(axis=(0, 1, 2)) / cnt
-        return {"mean": mean, "var": var}
+    def conv1(xb, ib):
+        xn, masks = _group_inputs(xb, ib, edges, n_groups)
+        h = jax.vmap(lambda p, xg: enhancer._conv(xg[..., None], p["w1"], p["b1"]))(
+            params, xn)  # [G, B, H, W, C]
+        return h, masks[..., None]
 
-    return jax.vmap(stats_one)(params, xn, masks)
+    def sums(acc, batch):
+        h, m = conv1(*batch)
+        return (acc[0] + (h * m).sum(axis=(1, 2, 3)),
+                acc[1] + m.sum(axis=(1, 2, 3))), None
+
+    C = params["b1"].shape[-1]
+    zero = jnp.zeros((n_groups, C), jnp.float32)
+    (total, cnt), _ = jax.lax.scan(
+        sums, (zero, jnp.zeros((n_groups, 1), jnp.float32)), batches)
+    cnt = jnp.maximum(cnt, 1.0)
+    mean = total / cnt
+
+    def sq_dev(acc, batch):
+        h, m = conv1(*batch)
+        return acc + ((h - mean[:, None, None, None]) ** 2 * m).sum(axis=(1, 2, 3)), None
+
+    var, _ = jax.lax.scan(sq_dev, zero, batches)
+    return {"mean": mean, "var": var / cnt}
 
 
 @partial(jax.jit, static_argnames=("n_groups", "residual_learning"))

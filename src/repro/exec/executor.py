@@ -200,11 +200,12 @@ def stream_compress(
     # the plan guarantees one uniform device-batch width (the short final run
     # is padded), so this stream's encode is exactly one compiled program —
     # register its identity so StreamReport can say whether it was fresh
+    from repro.launch.sharding import tile_devices
     from repro.sz.tiled import register_program_key
 
     programs_compiled = int(register_program_key(
         ("stream-encode", predictor, tuple(plan.tile), int(plan.batch_tiles),
-         order, int(levels), bool(device_entropy))))
+         order, int(levels), bool(device_entropy), len(tile_devices()))))
     want = (plan.shape, plan.tile, eb, backend, predictor, order, levels)
 
     start_tile, resumed_batches = 0, 0

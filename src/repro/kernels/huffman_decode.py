@@ -4,8 +4,8 @@ Mirrors the host ``_decode_lanes`` walk (``sz/entropy.py``): every chunk is an
 independent lane, all lanes step in lockstep, and one step performs a single
 k-bit multi-symbol LUT probe per lane — decoding *all* complete codes inside
 the window (up to S).  Codes longer than k bits resolve through the escape
-path: a fixed-iteration binary search over the left-aligned canonical
-codewords (the device form of the host's ``searchsorted``).
+path: the index of the last left-aligned canonical codeword at or below the
+window (the device form of the host's ``searchsorted``).
 
 Device-specific reformulations:
 
@@ -13,19 +13,23 @@ Device-specific reformulations:
   code boundaries only depend on the window's top 32 bits and the host
   searchsorted escape resolves identically (dispatch gates deeper legacy
   tables back to the host codec);
-* the window gather is two word loads combined with logical shifts (two-step
-  shifts keep every amount in [0, 31]);
+* every lookup is a one-hot select-and-sum over a table row, never an
+  in-kernel gather (Mosaic lowers only narrow 2D gathers).  Each chunk gets
+  its own word window (``words[c]`` starts at the word holding the chunk's
+  first bit, ``starts[c]`` is that bit's offset in it), so the word lookup
+  spans one window, not the whole stream; the LUT is one [8, 2**k] table
+  (count, consumed bits, up to six symbol ids), and the escape search is a
+  count of codewords at or below the window;
 * unsigned codeword comparison runs in int32 through the order-preserving
   ``x ^ 0x80000000`` map;
 * decoded ids land in the output via a one-hot accumulate over the chunk's
   symbol axis (ADD == OR on disjoint slots), not a scatter;
 * the lockstep loop is ``fori_loop`` over the worst case (chunk_size steps,
-  every probe yields >= 1 symbol) with a ``cond`` early-exit once all lanes
-  in the block hit their symbol targets.
+  every probe yields >= 1 symbol); lanes past their symbol target stop
+  advancing.
 
 Probe overshoot past a lane's symbol target is clamped exactly like the host
-path clamps in ``_expand_entries``; finished lanes stop advancing, so the
-word stream only needs two tail pad words.
+path clamps in ``_expand_entries``.
 """
 from __future__ import annotations
 
@@ -36,102 +40,98 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 _MININT = -2147483648  # x ^ MININT maps unsigned order onto int32 (weak literal)
+LUT_ROWS = 8  # count, consumed bits, then up to six symbol ids per probe
 
 
-def _decode_block(words, offsets, counts, lut_count, lut_bits, lut_ids,
-                  cw_map, order, len_sorted, *, chunk_size: int, k: int):
-    bb = offsets.shape[0]
-    S = lut_ids.shape[0]
-    n = order.shape[0]
+def _lookup(onehot, row):
+    """Per-lane table read: onehot [bb, N] bool, row [1, N] -> [bb, 1]."""
+    return jnp.sum(jnp.where(onehot, row, 0), axis=1, keepdims=True)
 
-    def probe(state):
+
+def _decode_block(words, starts, counts, lut, cw_map, order, len_sorted, *,
+                  chunk_size: int, k: int, n_ids: int):
+    """words [bb, W]; starts/counts [bb, 1]; lut [LUT_ROWS, 2**k];
+    cw_map/order/len_sorted [1, N] (padded past the alphabet: cw_map with
+    INT32_MAX, order/len_sorted with their last entry).  Returns [bb, cs]."""
+    bb, nw = words.shape
+    word_iota = jax.lax.broadcasted_iota(jnp.int32, (bb, nw), 1)
+    lut_iota = jax.lax.broadcasted_iota(jnp.int32, (bb, lut.shape[1]), 1)
+    cw_iota = jax.lax.broadcasted_iota(jnp.int32, (bb, cw_map.shape[1]), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (bb, chunk_size), 1)
+
+    def step(_, state):
         pos, cur, out = state
         wi = pos >> 5
         sh = pos & 31
-        h = jnp.take(words, wi)
-        nxt = jnp.take(words, wi + 1)
+        h = _lookup(word_iota == wi, words)
+        nxt = _lookup(word_iota == wi + 1, words)
         w = (h << sh) | jax.lax.shift_right_logical(
             jax.lax.shift_right_logical(nxt, 31 - sh), 1)
-        idx = jax.lax.shift_right_logical(w, 32 - k)
-        cnt = jnp.take(lut_count, idx)
-        nb = jnp.take(lut_bits, idx)
-        # escape: first code in the window is longer than k bits.  The first
-        # canonical code is 0 (maps to MININT <= any window), so low >= 1.
-        # mid clamps to n-1 so the fixed-count loop is a no-op once
-        # low == high == n (a window at/above the last codeword would
-        # otherwise probe index n and walk low past it).
-        wm = w ^ _MININT
-        low = jnp.zeros((bb,), jnp.int32)
-        high = jnp.full((bb,), n, jnp.int32)
-        for _ in range(max(n.bit_length(), 1)):
-            mid = jnp.minimum((low + high) >> 1, n - 1)
-            go = jnp.take(cw_map, mid) <= wm
-            low = jnp.where(go, mid + 1, low)
-            high = jnp.where(go, high, mid)
-        e_idx = low - 1
+        hot = lut_iota == jax.lax.shift_right_logical(w, 32 - k)
+        cnt = _lookup(hot, lut[0:1])
+        nb = _lookup(hot, lut[1:2])
+        # escape: the first code in the window is longer than k bits.  The
+        # first canonical code is 0 (maps to MININT <= any window), so the
+        # count is >= 1; padding past the alphabet repeats the last entry.
+        below = (cw_map <= (w ^ _MININT)).astype(jnp.int32)
+        e_hot = cw_iota == jnp.sum(below, axis=1, keepdims=True) - 1
         esc = cnt == 0
         cnt = jnp.where(esc, 1, cnt)
-        nb = jnp.where(esc, jnp.take(len_sorted, e_idx), nb)
+        nb = jnp.where(esc, _lookup(e_hot, len_sorted), nb)
         active = cur < counts
         take_n = jnp.where(active, jnp.minimum(cnt, counts - cur), 0)
-        slot = jax.lax.broadcasted_iota(jnp.int32, (bb, chunk_size), 1)
-        for j in range(S):
-            idj = jnp.take(lut_ids[j], idx)
+        for j in range(n_ids):
+            idj = _lookup(hot, lut[2 + j:3 + j])
             if j == 0:
-                idj = jnp.where(esc, jnp.take(order, e_idx), idj)
-            hit = (slot == (cur + j)[:, None]) & (take_n > j)[:, None]
-            out = out + jnp.where(hit, idj[:, None], 0)
+                idj = jnp.where(esc, _lookup(e_hot, order), idj)
+            hit = (slot == cur + j) & (take_n > j)
+            out = out + jnp.where(hit, idj, 0)
         pos = pos + jnp.where(active, nb, 0)
         return pos, cur + take_n, out
 
-    def body(_, state):
-        return jax.lax.cond(jnp.any(state[1] < counts), probe, lambda s: s, state)
-
-    init = (offsets, jnp.zeros((bb,), jnp.int32),
+    init = (starts, jnp.zeros((bb, 1), jnp.int32),
             jnp.zeros((bb, chunk_size), jnp.int32))
-    _, _, out = jax.lax.fori_loop(0, chunk_size, body, init)
+    _, _, out = jax.lax.fori_loop(0, chunk_size, step, init)
     return out
 
 
-def _kernel(words_ref, offsets_ref, counts_ref, lut_count_ref, lut_bits_ref,
-            lut_ids_ref, cw_map_ref, order_ref, len_sorted_ref, out_ref, *,
-            chunk_size: int, k: int):
+def _kernel(words_ref, starts_ref, counts_ref, lut_ref, cw_map_ref, order_ref,
+            len_sorted_ref, out_ref, *, chunk_size: int, k: int, n_ids: int):
     out_ref[...] = _decode_block(
-        words_ref[...], offsets_ref[...], counts_ref[...], lut_count_ref[...],
-        lut_bits_ref[...], lut_ids_ref[...], cw_map_ref[...], order_ref[...],
-        len_sorted_ref[...], chunk_size=chunk_size, k=k)
+        words_ref[...], starts_ref[...], counts_ref[...], lut_ref[...],
+        cw_map_ref[...], order_ref[...], len_sorted_ref[...],
+        chunk_size=chunk_size, k=k, n_ids=n_ids)
 
 
-@partial(jax.jit, static_argnames=("chunk_size", "k", "block_chunks", "interpret"))
-def huffman_decode_probe(words: jax.Array, offsets: jax.Array, counts: jax.Array,
-                         lut_count: jax.Array, lut_bits: jax.Array,
-                         lut_ids: jax.Array, cw_map: jax.Array,
-                         order: jax.Array, len_sorted: jax.Array, *,
-                         chunk_size: int, k: int, block_chunks: int = 8,
+@partial(jax.jit, static_argnames=("chunk_size", "k", "n_ids", "block_chunks",
+                                   "interpret"))
+def huffman_decode_probe(words: jax.Array, starts: jax.Array, counts: jax.Array,
+                         lut: jax.Array, cw_map: jax.Array, order: jax.Array,
+                         len_sorted: jax.Array, *, chunk_size: int, k: int,
+                         n_ids: int, block_chunks: int = 8,
                          interpret: bool = True) -> jax.Array:
-    """words: [NW] int32 (big-endian u32 stream words, >= 2 zero tail pad);
-    offsets/counts: [C] int32 per-chunk bit offsets / symbol targets.  Tables
-    are the codec's multi-symbol LUT split into parallel int32 arrays
-    (``HuffmanCodec._device_tables``).  Returns alphabet ids [C, chunk_size]
-    int32 (rows zero-padded past each chunk's count)."""
-    C = offsets.shape[0]
-    bb = min(block_chunks, C)
-    Cp = -(-C // bb) * bb
-    if Cp != C:
-        offsets = jnp.pad(offsets, (0, Cp - C))
-        counts = jnp.pad(counts, (0, Cp - C))  # count 0 => lane never activates
+    """words: [C, W] int32 per-chunk word windows (big-endian u32 stream
+    words from the one holding the chunk's first bit; zeros past the
+    stream); starts/counts: [C, 1] int32 first-bit offset in the window /
+    symbol target.  Tables are the codec's multi-symbol LUT and canonical
+    order (``HuffmanCodec._device_tables``).  Returns alphabet ids
+    [C, chunk_size] int32 (rows zero-padded past each chunk's count)."""
+    C = words.shape[0]
+    bb = block_chunks
+    pad = (-C) % bb
+    if pad:  # count 0 => the lane never activates
+        words = jnp.pad(words, ((0, pad), (0, 0)))
+        starts = jnp.pad(starts, ((0, pad), (0, 0)))
+        counts = jnp.pad(counts, ((0, pad), (0, 0)))
     full = lambda a: pl.BlockSpec(a.shape, lambda i: (0,) * a.ndim)
+    rows = lambda a: pl.BlockSpec((bb, a.shape[1]), lambda i: (i, 0))
     out = pl.pallas_call(
-        partial(_kernel, chunk_size=chunk_size, k=k),
-        grid=(Cp // bb,),
-        in_specs=[full(words),
-                  pl.BlockSpec((bb,), lambda i: (i,)),
-                  pl.BlockSpec((bb,), lambda i: (i,)),
-                  full(lut_count), full(lut_bits), full(lut_ids),
+        partial(_kernel, chunk_size=chunk_size, k=k, n_ids=n_ids),
+        grid=((C + pad) // bb,),
+        in_specs=[rows(words), rows(starts), rows(counts), full(lut),
                   full(cw_map), full(order), full(len_sorted)],
         out_specs=pl.BlockSpec((bb, chunk_size), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Cp, chunk_size), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((C + pad, chunk_size), jnp.int32),
         interpret=interpret,
-    )(words, offsets, counts, lut_count, lut_bits, lut_ids, cw_map, order,
-      len_sorted)
+    )(words, starts, counts, lut, cw_map, order, len_sorted)
     return out[:C]

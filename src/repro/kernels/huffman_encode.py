@@ -76,10 +76,12 @@ def huffman_encode_pack(lens: jax.Array, codes: jax.Array, *,
     int32).
 
     The one-hot intermediate is [BB, CS, CS] int32, so the block height BB is
-    sized to keep it around ~1M cells (mirrors ``symbol_hist``'s bound).
+    sized to keep it around ~1M cells (mirrors ``symbol_hist``'s bound),
+    rounded down to the TPU's 8-row sublane tile (at least 8; short inputs
+    are padded up to one block).
     """
     C, cs = lens.shape
-    bb = max(1, min(C, 1_000_000 // max(cs * cs, 1)))
+    bb = max(8, 1_000_000 // max(cs * cs, 1) // 8 * 8)
     Cp = -(-C // bb) * bb
     if Cp != C:
         pad = ((0, Cp - C), (0, 0))

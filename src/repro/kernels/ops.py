@@ -83,10 +83,14 @@ def symbol_hist_op(symbols, *, n_bins: int, use_pallas: bool | None = None,
     sentinel bin, along with lane padding). Returns hist int32 [n_bins]."""
     flat = jnp.reshape(symbols, (-1,))
     sentinel = n_bins
-    bins = n_bins + 1
+    # bins round up to a power of two (at least 128, always > n_bins so the
+    # sentinel fits): one compiled kernel then serves every alphabet span in
+    # the bucket, instead of one compile per distinct span
+    bins = max(128, 1 << n_bins.bit_length())
     flat = jnp.where((flat >= 0) & (flat < n_bins), flat, sentinel).astype(jnp.int32)
-    # block size bounds the [BB, 128, bins] one-hot intermediate to ~1M cells
-    bb = max(1, min(256, 8192 // bins))
+    # block size bounds the [BB, 128, bins] one-hot intermediate to ~1M cells,
+    # in whole 8-row sublane tiles (the TPU block rule); rows pad to a block
+    bb = max(8, min(256, 8192 // bins) // 8 * 8)
     rows = -(-max(int(flat.shape[0]), 1) // 128)
     rows = -(-rows // bb) * bb
     pad = rows * 128 - flat.shape[0]
@@ -117,25 +121,25 @@ def huffman_encode_op(lens, codes, *, use_pallas: bool | None = None,
     return ref.huffman_encode_ref(lens, codes)
 
 
-def huffman_decode_op(words, offsets, counts, lut_count, lut_bits, lut_ids,
-                      cw_map, order, len_sorted, *, chunk_size: int, k: int,
+def huffman_decode_op(words, starts, counts, lut, cw_map, order, len_sorted, *,
+                      chunk_size: int, k: int, n_ids: int,
                       use_pallas: bool | None = None,
                       interpret: bool | None = None):
     """Lockstep multi-symbol-LUT Huffman decode probe.
 
-    words: [NW] int32 big-endian u32 stream words (>= 2 zero tail words);
-    offsets/counts: [C] int32; tables from
-    ``HuffmanCodec._device_tables``.  Returns alphabet ids [C, chunk_size]
-    int32 (zero-padded past each chunk's count)."""
+    words: [C, W] int32 per-chunk windows of big-endian u32 stream words;
+    starts/counts: [C, 1] int32 first-bit offsets / symbol targets; tables
+    from ``HuffmanCodec._device_tables``.  Returns alphabet ids
+    [C, chunk_size] int32 (zero-padded past each chunk's count)."""
     use = _on_tpu() if use_pallas is None else use_pallas
     if use:
         return huffman_decode_probe(
-            words, offsets, counts, lut_count, lut_bits, lut_ids, cw_map,
-            order, len_sorted, chunk_size=chunk_size, k=k,
+            words, starts, counts, lut, cw_map, order, len_sorted,
+            chunk_size=chunk_size, k=k, n_ids=n_ids,
             interpret=not _on_tpu() if interpret is None else interpret)
-    return ref.huffman_decode_ref(words, offsets, counts, lut_count, lut_bits,
-                                  lut_ids, cw_map, order, len_sorted,
-                                  chunk_size=chunk_size, k=k)
+    return ref.huffman_decode_ref(words, starts, counts, lut, cw_map, order,
+                                  len_sorted, chunk_size=chunk_size, k=k,
+                                  n_ids=n_ids)
 
 
 def group_hist_op(x, edges, *, n_groups: int, use_pallas: bool | None = None,

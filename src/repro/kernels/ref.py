@@ -54,15 +54,14 @@ def huffman_encode_ref(lens: jax.Array, codes: jax.Array) -> tuple[jax.Array, ja
     return words, totals[:, 0]
 
 
-def huffman_decode_ref(words, offsets, counts, lut_count, lut_bits, lut_ids,
-                       cw_map, order, len_sorted, *, chunk_size: int,
-                       k: int) -> jax.Array:
+def huffman_decode_ref(words, starts, counts, lut, cw_map, order, len_sorted,
+                       *, chunk_size: int, k: int, n_ids: int) -> jax.Array:
     """Lockstep multi-symbol LUT decode probe over all chunks at once.
     Returns alphabet ids [C, chunk_size] int32."""
     from repro.kernels.huffman_decode import _decode_block
 
-    return _decode_block(words, offsets, counts, lut_count, lut_bits, lut_ids,
-                         cw_map, order, len_sorted, chunk_size=chunk_size, k=k)
+    return _decode_block(words, starts, counts, lut, cw_map, order, len_sorted,
+                         chunk_size=chunk_size, k=k, n_ids=n_ids)
 
 
 def group_hist_ref(x: jax.Array, edges: jax.Array) -> tuple[jax.Array, jax.Array]:

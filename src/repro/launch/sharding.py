@@ -8,6 +8,7 @@ on (the 405B/671B training cells), else to None.
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 
 import jax
@@ -142,15 +143,45 @@ def batch_pspec(mesh, *, seq_axis=None) -> P:
     return P(batch_axes_of(mesh), seq_axis)
 
 
+# Device set the tile fan-out uses when a caller names none: every local
+# device, unless pinned by :func:`pin_tile_devices`.  Process-wide on
+# purpose, like JAX's own device list: a region server decodes on its
+# request threads, and each of them must see the same pin.
+_PINNED_DEVICES: tuple | None = None
+
+
+@contextlib.contextmanager
+def pin_tile_devices(devices):
+    """Within the block, :func:`tile_mesh`, :func:`device_round` and
+    :func:`map_tiles` default to ``devices`` instead of ``jax.devices()``.
+
+    This is how one process runs the same path on one device and on a
+    multi-device mesh: streamed ingest, decode and serving all build their
+    mesh through these helpers.  Pins do not nest."""
+    global _PINNED_DEVICES
+    if _PINNED_DEVICES is not None:
+        raise RuntimeError("tile devices are already pinned")
+    _PINNED_DEVICES = tuple(devices)
+    try:
+        yield _PINNED_DEVICES
+    finally:
+        _PINNED_DEVICES = None
+
+
+def tile_devices() -> tuple:
+    """The device set tile fan-out uses by default (see :func:`pin_tile_devices`)."""
+    return _PINNED_DEVICES if _PINNED_DEVICES is not None else tuple(jax.devices())
+
+
 def tile_mesh(devices=None):
-    """1D mesh over all local devices for tile-grid fan-out (axis ``tiles``).
+    """1D mesh over the tile devices for tile-grid fan-out (axis ``tiles``).
 
     The tiled compression engine (repro.sz.tiled) treats the tile batch as a
     pure data axis: every tile is an independent prediction+quantization
     domain, so compress/decompress shard with no collectives at all."""
     import numpy as np
 
-    devs = np.asarray(jax.devices() if devices is None else devices)
+    devs = np.asarray(tile_devices() if devices is None else devices)
     return jax.sharding.Mesh(devs, ("tiles",))
 
 
@@ -161,14 +192,14 @@ def device_round(n: int, devices: int | None = None) -> int:
     so ``map_tiles`` fan-out pads nothing in steady state; widths smaller
     than the device count stay as-is (the pad-with-repeats path handles
     them, and shrinking to 0 would be worse)."""
-    d = len(jax.devices()) if devices is None else int(devices)
+    d = len(tile_devices()) if devices is None else int(devices)
     if d <= 1 or n <= d:
         return max(1, int(n))
     return (int(n) // d) * d
 
 
 def map_tiles(fn, tiles, *extra, mesh=None):
-    """Fan a tile-batched op across the device mesh via ``shard_map``.
+    """Fan a tile-batched op across the device mesh via ``jax.shard_map``.
 
     ``tiles`` may be one array or a pytree of arrays sharing the tile batch
     on axis 0 (e.g. the interp predictor's ``(codes, omask, ovals)``), and
@@ -183,7 +214,6 @@ def map_tiles(fn, tiles, *extra, mesh=None):
     if n <= 1:
         return fn(tiles, *extra)
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
 
     B = jax.tree.leaves(tiles)[0].shape[0]
     pad = (-B) % n
@@ -191,8 +221,8 @@ def map_tiles(fn, tiles, *extra, mesh=None):
         tiles = jax.tree.map(
             lambda t: jnp.concatenate([t, jnp.repeat(t[:1], pad, axis=0)]), tiles)
     in_specs = (P("tiles"),) + (P(),) * len(extra)
-    out = shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=P("tiles"),
-                    check_rep=False)(tiles, *extra)
+    out = jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=P("tiles"),
+                        check_vma=False)(tiles, *extra)
     return jax.tree.map(lambda o: o[:B], out) if pad else out
 
 

@@ -296,10 +296,16 @@ class VolumePool:
             from repro.cli import parse_roi
 
             roi = parse_roi(roi)
-        canon = normalize_roi(roi, tuple(vol.shape))
+        # an integer index reads the same voxels as its one-wide slice but
+        # drops that axis, so the squeezed axes are part of the identity
+        shape = tuple(vol.shape)
+        squeeze = tuple(isinstance(r, int) for r in roi)
+        canon = normalize_roi(
+            tuple((r + d * (r < 0), r + d * (r < 0) + 1) if sq else r
+                  for r, sq, d in zip(roi, squeeze, shape)), shape)
         codec_path = "pallas" if _accel_default() else "host"
         digest = hashlib.sha1(
-            f"{self._etag_seed(name, vol)}|{canon}|{codec_path}".encode()
+            f"{self._etag_seed(name, vol)}|{canon}|{squeeze}|{codec_path}".encode()
         ).hexdigest()
         return f'"{digest[:32]}"', roi
 
@@ -536,13 +542,13 @@ def fetch_region(url: str, name: str, roi: str, timeout: float = 60.0,
     which returns ``(None, meta)`` on a 304.  Raises ``RuntimeError`` with
     the server's error message on other non-200s."""
     from urllib.error import HTTPError
-    from urllib.request import Request, urlopen
+    from urllib.request import Request
 
     req = Request(f"{url}/v/{name}/region?roi={roi}")
     if etag is not None:
         req.add_header("If-None-Match", etag)
     try:
-        with urlopen(req, timeout=timeout) as r:
+        with _direct_opener().open(req, timeout=timeout) as r:
             meta = json.loads(r.headers.get("X-Repro-Meta", "{}"))
             meta["etag"] = r.headers.get("ETag")
             arr = np.load(io.BytesIO(r.read()))
@@ -557,7 +563,13 @@ def fetch_region(url: str, name: str, roi: str, timeout: float = 60.0,
 
 def fetch_json(url: str, path: str, timeout: float = 60.0) -> dict:
     """GET a JSON endpoint (``/healthz``, ``/metrics``, ``/v/<n>/info``)."""
-    from urllib.request import urlopen
-
-    with urlopen(f"{url}{path}", timeout=timeout) as r:
+    with _direct_opener().open(f"{url}{path}", timeout=timeout) as r:
         return json.loads(r.read())
+
+
+def _direct_opener():
+    """URL opener that ignores proxy environment variables: these clients
+    talk to a daemon on this machine, which a proxy could not reach."""
+    from urllib.request import ProxyHandler, build_opener
+
+    return build_opener(ProxyHandler({}))

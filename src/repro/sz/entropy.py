@@ -21,6 +21,7 @@ import heapq
 import os
 import struct
 import sys
+import threading
 import zlib
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
@@ -37,6 +38,7 @@ _LUT_BITS = 12  # primary decode-table width cap (2**k uint64 entries)
 _FMT_CODE_LEN = 32  # FROZEN in the hc/hZ blob format (chunk-table width rule)
 _MAX_CODE_LEN = _FMT_CODE_LEN  # encoder policy; must never exceed _FMT_CODE_LEN
 _ACCEL_SPAN = 4096  # dense alphabet span served by the symbol_hist kernel
+_PROBE_ALPHABET = 1 << 14  # widest alphabet the device decode probe takes
 _DENSE_SPAN = 1 << 22  # host bincount beyond this falls back to np.unique
 
 
@@ -124,15 +126,45 @@ def _canonical_codes(lengths: np.ndarray) -> np.ndarray:
 
 
 def _accel_default() -> bool:
-    # jax missing entirely or unable to initialize a backend means "no
-    # accelerator" — anything else (KeyboardInterrupt, a typo'd plugin
-    # import raising AttributeError, ...) is a real bug and must propagate
-    try:
-        import jax
+    """Device entropy (``symbol_hist`` counts, the Pallas encode pack and
+    decode probe) is the TPU's path; every other platform runs the host
+    codec."""
+    import jax
 
-        return jax.default_backend() == "tpu"
-    except (ImportError, RuntimeError):
-        return False
+    return jax.default_backend() == "tpu"
+
+
+# Process-wide count of which implementation each Huffman lane went
+# through: ``pack_device``/``pack_host`` per encoded lane, ``probe_device``/
+# ``probe_host`` per decoded lane.  ``pack_fallback``/``probe_fallback``
+# count lanes for which the device path was asked for but the host ran them
+# (an ineligible codec or stream), so a run on the chip can prove that no
+# lane left the device path unannounced.
+_PATH_LOCK = threading.Lock()
+_PATH_KEYS = ("pack_device", "pack_host", "pack_fallback", "probe_device",
+              "probe_host", "probe_fallback")
+_PATHS = dict.fromkeys(_PATH_KEYS, 0)
+
+
+def _count_lane(stage: str, asked: bool, on_device: bool) -> None:
+    """Count one lane of ``stage`` ("pack" or "probe")."""
+    with _PATH_LOCK:
+        if on_device:
+            _PATHS[f"{stage}_device"] += 1
+        else:
+            _PATHS[f"{stage}_host"] += 1
+            _PATHS[f"{stage}_fallback"] += asked
+
+
+def entropy_path_stats() -> dict:
+    """Snapshot of the per-lane entropy implementation counters."""
+    with _PATH_LOCK:
+        return dict(_PATHS)
+
+
+def reset_entropy_path_stats() -> None:
+    with _PATH_LOCK:
+        _PATHS.update(dict.fromkeys(_PATH_KEYS, 0))
 
 
 def _accel_hist(flat: np.ndarray, lo: int, span: int) -> np.ndarray:
@@ -576,34 +608,55 @@ class HuffmanCodec:
         return 0 < n < (1 << 31) and int(self.lengths.max()) <= 32
 
     def _device_tables(self):
-        """Multi-symbol LUT split into parallel int32 arrays for the decode
-        kernel (packed uint64 entries have no device analogue).  Cached;
-        ``None`` when the codec is device-ineligible."""
+        """Decode-probe tables as int32 device rows (packed uint64 entries
+        have no device analogue).  Cached; ``None`` when the codec is
+        device-ineligible or its alphabet is too wide for the probe's
+        per-step escape count (``_PROBE_ALPHABET``).
+
+        ``lut`` is [8, 2**_LUT_BITS]: count, consumed bits, then up to six
+        symbol ids per window.  A LUT narrower than ``_LUT_BITS`` (every code
+        shorter) repeats each entry over the ignored low bits, so one probe
+        width serves every codec.  ``cw_map``/``order``/``len_sorted`` are
+        [1, N] rows padded to a power of two (at least 128): ``cw_map`` with
+        INT32_MAX, the others with their last entry, so a count of codewords
+        at or below any window indexes a real entry."""
         cached = getattr(self, "_dev_tables", None)
         if cached is not None:
             return cached or None
-        if not self._device_eligible():
+        n = len(self.alphabet)
+        if not self._device_eligible() or n > _PROBE_ALPHABET:
             self._dev_tables = False
             return None
+        from repro.kernels.huffman_decode import LUT_ROWS
+
         mt = self._multi_tables()
         t = mt.tables
         base = _id_shift0(mt.B)
         mask = np.uint64((1 << mt.B) - 1)
-        lut_ids = np.stack([
-            ((mt.mlut >> np.uint64(base + j * mt.B)) & mask).astype(np.int32)
-            for j in range(mt.S)])
+        mlut = np.repeat(mt.mlut, 1 << (_LUT_BITS - t.k))
+        lut = np.zeros((LUT_ROWS, mlut.size), np.int32)
+        lut[0] = mlut & np.uint64(0xFF)
+        lut[1] = (mlut >> np.uint64(8)) & np.uint64(0xFF)
+        for j in range(mt.S):
+            lut[2 + j] = (mlut >> np.uint64(base + j * mt.B)) & mask
         # top-32 truncation is faithful: codes occupy the top <= 32 bits, so
         # interval boundaries only depend on the window's top 32 bits, and
         # the XOR maps unsigned order onto int32 for the kernel's compares
         cw32 = (t.cw_left >> np.uint64(32)).astype(np.uint32)
+        width = max(128, 1 << (n - 1).bit_length())
+
+        def row(a, fill):
+            out = np.full((1, width), fill, np.int32)
+            out[0, :n] = a
+            return out
+
         dev = {
-            "lut_count": (mt.mlut & np.uint64(0xFF)).astype(np.int32),
-            "lut_bits": ((mt.mlut >> np.uint64(8)) & np.uint64(0xFF)).astype(np.int32),
-            "lut_ids": lut_ids,
-            "cw_map": (cw32 ^ np.uint32(0x80000000)).view(np.int32),
-            "order": t.order.astype(np.int32),
-            "len_sorted": t.L_sorted.astype(np.int32),
-            "k": t.k,
+            "lut": lut,
+            "cw_map": row((cw32 ^ np.uint32(0x80000000)).view(np.int32),
+                          np.iinfo(np.int32).max),
+            "order": row(t.order, t.order[-1]),
+            "len_sorted": row(t.L_sorted, t.L_sorted[-1]),
+            "n_ids": mt.S,
         }
         self._dev_tables = dev
         return dev
@@ -680,23 +733,32 @@ class HuffmanCodec:
             if not 0 <= c0 < c1 <= C:
                 raise ValueError(f"chunk range {chunk_range} outside [0, {C})")
             offsets, counts = offsets[c0:c1], counts[c0:c1]
+            chunk_bits = chunk_bits[c0:c1]
             n_symbols = int(counts.sum())
         import jax.numpy as jnp
 
         from repro.kernels import ops
 
+        # one word window per chunk, from the word holding its first bit to
+        # one word past its last (the probe reads word pairs); the width
+        # rounds up to whole 128-lane rows, zeros past the stream
+        first = offsets >> 5
+        starts = offsets & 31
+        span = (starts + chunk_bits + 31) >> 5
+        W = -(-(int(span.max()) + 1) // 128) * 128
         raw = np.frombuffer(stream, np.uint8)
-        # pad to a word boundary + 2 zero tail words for the wi+1 gather
-        padded = np.zeros(raw.size + (-raw.size) % 4 + 8, np.uint8)
+        padded = np.zeros(max(4 * (int(first.max()) + W), raw.size + 3) // 4 * 4,
+                          np.uint8)
         padded[: raw.size] = raw
         words = padded.view(">u4").astype(np.uint32).view(np.int32)
+        win = words[first[:, None] + np.arange(W)]
         ids = ops.huffman_decode_op(
-            jnp.asarray(words), jnp.asarray(offsets), jnp.asarray(counts),
-            jnp.asarray(dev["lut_count"]), jnp.asarray(dev["lut_bits"]),
-            jnp.asarray(dev["lut_ids"]), jnp.asarray(dev["cw_map"]),
-            jnp.asarray(dev["order"]), jnp.asarray(dev["len_sorted"]),
-            chunk_size=chunk_size, k=dev["k"],
-            use_pallas=True, interpret=interpret)
+            jnp.asarray(win), jnp.asarray(starts[:, None]),
+            jnp.asarray(counts[:, None]), jnp.asarray(dev["lut"]),
+            jnp.asarray(dev["cw_map"]), jnp.asarray(dev["order"]),
+            jnp.asarray(dev["len_sorted"]), chunk_size=chunk_size,
+            k=_LUT_BITS, n_ids=dev["n_ids"], use_pallas=True,
+            interpret=interpret)
         # only the last selected chunk can be short, so row-major flatten +
         # truncate is exactly the symbol stream
         flat_ids = np.asarray(ids).reshape(-1)[:n_symbols]
@@ -743,8 +805,9 @@ def encode_codes(
     ``use_pallas`` routes the bit-stream pack through the device encode
     kernel (``kernels/huffman_encode.py``): ``None`` auto-detects (device
     path on TPU only), ``True`` forces it (interpret mode off-TPU), ``False``
-    keeps the host pack.  Bytes are bit-identical either way — device-
-    ineligible codecs silently fall back to host."""
+    keeps the host pack.  Bytes are bit-identical either way; a device-
+    ineligible codec packs on the host and is counted as ``pack_fallback``
+    in :func:`entropy_path_stats`."""
     flat = np.ascontiguousarray(codes, np.int32).ravel()
     if backend == "zlib":
         # int32 -> int16 when it fits (usual case): halves the zlib input
@@ -762,6 +825,7 @@ def encode_codes(
         n_chunks = -(-n // cs) if n else 0
         dev = _accel_default() if use_pallas is None else use_pallas
         got = codec._device_pack(flat, cs) if dev and n_chunks else None
+        _count_lane("pack", bool(dev and n_chunks), got is not None)
         if got is not None:
             stream, chunk_bits, total = got
         else:
@@ -830,7 +894,8 @@ def decode_codes(blob: bytes, shape: tuple[int, ...], *, workers: int | None = N
     ``use_pallas`` routes chunked hc/hZ streams through the device decode
     kernel (``kernels/huffman_decode.py``): ``None`` auto-detects (TPU only),
     ``True`` forces it (interpret mode off-TPU), ``False`` keeps the host
-    walk.  Device-ineligible streams silently fall back to host."""
+    walk.  Device-ineligible streams decode on the host, counted as
+    ``probe_fallback`` in :func:`entropy_path_stats`."""
     assert blob[:4] == _MAGIC, "bad entropy blob"
     tag = blob[4:6]
     if tag in (b"z2", b"z4"):
@@ -856,6 +921,7 @@ def decode_codes(blob: bytes, shape: tuple[int, ...], *, workers: int | None = N
         if dev:
             out = codec.decode_chunked_device(stream, n, cs, chunk_bits,
                                               total_bits=total)
+        _count_lane("probe", bool(dev), out is not None)
         if out is None:
             out = codec.decode_chunked(stream, n, cs, chunk_bits,
                                        total_bits=total, workers=workers)
@@ -908,6 +974,7 @@ def decode_codes_range(blob: bytes, lo: int, hi: int, *, workers: int | None = N
             out = codec.decode_chunked_device(stream, n, cs, chunk_bits,
                                               total_bits=total,
                                               chunk_range=(c0, c1))
+        _count_lane("probe", bool(dev), out is not None)
         if out is None:
             out = codec.decode_chunked(stream, n, cs, chunk_bits, total_bits=total,
                                        workers=workers, chunk_range=(c0, c1))

@@ -464,6 +464,8 @@ def _lint(*argv, cwd=ROOT):
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(ROOT, "src"), env.get("PYTHONPATH", "")]).rstrip(os.pathsep)
     env.setdefault("JAX_PLATFORMS", "cpu")
+    # the entry point turns on the persistent compile cache; tests keep it off
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     return subprocess.run(
         [sys.executable, "-m", "repro.cli", "lint", *argv],
         cwd=cwd, env=env, capture_output=True, text=True, timeout=300)

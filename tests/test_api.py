@@ -320,3 +320,30 @@ def test_cli_gwds_field_selection(tmp_path, volume, capsys):
     assert cli.parse_roi("3,::2") == (3, slice(None, None, 2))
     with pytest.raises(ValueError):
         cli.parse_roi("1:2:3:4")
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir_rule(tmp_path, env_dir):
+    """Entry points' compile cache: JAX_COMPILATION_CACHE_DIR when set (and
+    no other path), else the fixed <checkout>/.jax_cache.  Checked in a
+    child process that compiles nothing, so no test writes a cache entry."""
+    import subprocess
+    import sys
+
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(root, "src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    code = ("import jax\n"
+            "from repro.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    returned, configured = proc.stdout.splitlines()
+    want = (str(tmp_path / env_dir) if env_dir is not None
+            else os.path.join(root, ".jax_cache"))
+    assert returned == configured == want

@@ -13,6 +13,8 @@ def test_run_fast_smoke():
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(ROOT, "src"), ROOT, env.get("PYTHONPATH", "")]).rstrip(os.pathsep)
     env.setdefault("JAX_PLATFORMS", "cpu")
+    # the entry point turns on the persistent compile cache; tests keep it off
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.run", "--fast", "--only", "throughput"],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=840,

@@ -1,0 +1,125 @@
+"""Main-path programs compiled for a described TPU v5e, at real widths.
+
+No chip is needed: the TPU compiler runs here against a described v5e
+topology and refuses what the chip would refuse (block shapes off the 8x128
+tiling, ops Mosaic cannot lower, programs over the 16 GiB of HBM).  Passing
+is not a chip run; ``chip_smoke.py`` is.
+
+The topology is described inside a module fixture, never at import time:
+only the worker that runs this file loads the TPU library.
+"""
+from __future__ import annotations
+
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+HBM_BYTES = 16 << 30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # any failure to describe the chip means: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+def test_lorenzo_quant_tiles_compiles(one_chip):
+    from repro.kernels.lorenzo_quant import lorenzo_quant_tiles
+
+    c = _compile(lambda x: lorenzo_quant_tiles(x, 0.01, interpret=False),
+                 _spec(one_chip, (8, 64, 64, 64)))
+    assert _has_kernel(c)
+
+
+def test_huffman_encode_pack_compiles(one_chip):
+    """One 64^3 lane: 1024 chunks of 256 symbols."""
+    from repro.kernels.huffman_encode import huffman_encode_pack
+
+    lanes = _spec(one_chip, (1024, 256), jnp.int32)
+    c = _compile(lambda l, w: huffman_encode_pack(l, w, interpret=False),
+                 lanes, lanes)
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("n_bins", [32, 256, 4096],
+                         ids=["33-bins", "257-bins", "4097-bins"])
+def test_symbol_hist_compiles(one_chip, n_bins):
+    """Alphabet spans up to the entropy stage's ``_ACCEL_SPAN`` (the bin
+    count includes the out-of-range sentinel)."""
+    from repro.kernels import ops
+
+    c = _compile(lambda s: ops.symbol_hist_op(s, n_bins=n_bins, use_pallas=True,
+                                              interpret=False),
+                 _spec(one_chip, (64 ** 3,), jnp.int32))
+    assert _has_kernel(c)
+
+
+def test_huffman_decode_probe_compiles(one_chip):
+    """One 64^3 lane: 1024 chunk windows of 128 words, the 12-bit LUT, a
+    1024-symbol alphabet, six ids per probe."""
+    from repro.kernels.huffman_decode import LUT_ROWS, huffman_decode_probe
+
+    i32 = partial(_spec, one_chip, dtype=jnp.int32)
+    k, n = 12, 1024
+    c = _compile(lambda *a: huffman_decode_probe(*a, chunk_size=256, k=k,
+                                                 n_ids=6, interpret=False),
+                 i32((1024, 128)), i32((1024, 1)), i32((1024, 1)),
+                 i32((LUT_ROWS, 1 << k)), i32((1, n)), i32((1, n)), i32((1, n)))
+    assert _has_kernel(c)
+
+
+def _enhancer_specs(sharding, G=20, C=9):
+    from repro.core import enhancer
+
+    params = jax.eval_shape(lambda: jax.vmap(lambda key: enhancer.init_params(key, C))(
+        jax.random.split(jax.random.PRNGKey(0), G)))
+    params = jax.tree.map(lambda a: _spec(sharding, a.shape, a.dtype), params)
+    bn = {"mean": _spec(sharding, (G, C)), "var": _spec(sharding, (G, C))}
+    return params, bn
+
+
+@pytest.mark.parametrize("tiles", [32, 128])
+@pytest.mark.parametrize("program", ["_gate_groups", "_bn_calibrate"])
+def test_enhancer_pass_fits_hbm(one_chip, program, tiles):
+    """BN calibration and the gate over a reservoir of 64^3 tiles at the
+    published width (G=20, C=9): 32 tiles is the default reservoir at a
+    256 MiB budget, 128 tiles a 1 GiB one."""
+    from repro.core import trainer
+
+    G = 20
+    params, bn = _enhancer_specs(one_chip, G=G)
+    n = tiles * 64
+    xs = _spec(one_chip, (n, 64, 64))
+    ids = _spec(one_chip, (n, 64, 64), jnp.int32)
+    edges, rscale = _spec(one_chip, (G + 1,)), _spec(one_chip, (G,))
+    if program == "_gate_groups":
+        fn = partial(trainer._gate_groups, n_groups=G)
+        args = (params, bn, xs, xs, ids, edges, rscale)
+    else:
+        fn = partial(trainer._bn_calibrate, n_groups=G)
+        args = (params, xs, ids, edges)
+    m = _compile(fn, *args).memory_analysis()
+    used = m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes
+    assert used < HBM_BYTES, f"{program} at {tiles} tiles needs {used / 2**30:.2f} GiB"

@@ -301,3 +301,24 @@ def test_device_host_fuzz_parity():
         assert dev == host, f"n={n} alpha={alpha} cs={cs} {backend}"
         np.testing.assert_array_equal(
             decode_codes(dev, codes.shape, use_pallas=True), codes)
+
+
+def test_entropy_path_counters_report_every_lane():
+    """Each Huffman lane is counted under the path that ran it; a lane the
+    device was asked for but the host ran (here: an alphabet wider than the
+    decode probe takes) is counted as a fallback, never silently."""
+    from repro.sz import entropy
+
+    rng = np.random.default_rng(21)
+    small = rng.integers(-40, 40, size=5000).astype(np.int32)
+    wide = rng.permutation(np.arange(entropy._PROBE_ALPHABET + 100, dtype=np.int32))
+    entropy.reset_entropy_path_stats()
+    blob = encode_codes(small, "huffman+zlib", use_pallas=True)
+    assert blob == encode_codes(small, "huffman+zlib", use_pallas=False)
+    np.testing.assert_array_equal(decode_codes(blob, small.shape, use_pallas=True), small)
+    np.testing.assert_array_equal(decode_codes(blob, small.shape, use_pallas=False), small)
+    wide_blob = encode_codes(wide, "huffman", use_pallas=False)
+    np.testing.assert_array_equal(decode_codes(wide_blob, wide.shape, use_pallas=True), wide)
+    assert entropy.entropy_path_stats() == {
+        "pack_device": 1, "pack_host": 2, "pack_fallback": 0,
+        "probe_device": 1, "probe_host": 2, "probe_fallback": 1}

@@ -86,3 +86,44 @@ def test_residual_beats_regular(nyx_small):
         out = enhance(recon, model)
         out_mse[mode] = float(metrics.mse(x, out))
     assert out_mse[True] < out_mse[False]
+
+
+def test_bn_calibrate_and_gate_match_whole_array_formulas():
+    """The BN calibration and the gate scan slice batches (so no program
+    grows with the reservoir); on 37 slices of 64x64 (three batches, the
+    last one padded) they still give the whole-array masked statistics and
+    the per-group MSE test."""
+    import jax
+
+    from repro.core import enhancer, grouping, trainer
+
+    G, n = 5, 37
+    rng = np.random.default_rng(11)
+    xs = jnp.asarray(rng.normal(size=(n, 64, 64)).astype(np.float32))
+    edges = grouping.compute_edges(xs, G, "quantile")
+    ids = grouping.assign_groups(xs, edges)
+    params = jax.vmap(lambda k: enhancer.init_params(k, 9))(
+        jax.random.split(jax.random.PRNGKey(3), G))
+    xn, masks = trainer._group_inputs(xs, ids, edges, G)
+
+    h = jax.vmap(lambda p, x: enhancer._conv(x[..., None], p["w1"], p["b1"]))(params, xn)
+    m = masks[..., None]
+    cnt = jnp.maximum(m.sum(axis=(1, 2, 3)), 1.0)
+    mean = (h * m).sum(axis=(1, 2, 3)) / cnt
+    var = ((h - mean[:, None, None, None]) ** 2 * m).sum(axis=(1, 2, 3)) / cnt
+    bn = trainer._bn_calibrate(params, xs, ids, edges, n_groups=G)
+    np.testing.assert_allclose(bn["mean"], mean, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(bn["var"], var, rtol=1e-5, atol=1e-6)
+
+    # residual = what the even-numbered groups' enhancers predict, so their
+    # gates open and the odd groups' gates shut
+    rscale = jnp.full((G,), 0.05)
+    preds = jax.vmap(lambda p, st, x: enhancer.apply(p, st, x, train=False)[0])(
+        params, bn, xn) * rscale[:, None, None, None]
+    keep = (jnp.arange(G) % 2 == 0)[:, None, None, None]
+    rs = (preds * masks * keep).sum(axis=0) + 1e-3
+    want = ((((rs[None] - preds) * masks) ** 2).sum(axis=(1, 2, 3))
+            < ((rs[None] * masks) ** 2).sum(axis=(1, 2, 3)))
+    gate = trainer._gate_groups(params, bn, xs, rs, ids, edges, rscale, n_groups=G)
+    np.testing.assert_array_equal(np.asarray(gate), np.asarray(want, np.float32))
+    assert 0 < float(gate.sum()) < G

@@ -186,29 +186,30 @@ def test_huffman_encode_matches_ref(cs):
 
 def test_huffman_decode_matches_ref():
     """Pallas decode probe == the pure-jnp block oracle == the source codes,
-    through the real codec tables and a real packed stream."""
+    through the real codec tables and a real packed stream cut into the
+    probe's per-chunk word windows."""
+    from repro.sz.entropy import _LUT_BITS
+
     cs = 64
     codec, codes, lens, cws = _huffman_kernel_inputs(cs=cs)
     stream, chunk_bits, _total = codec._device_pack(codes, cs, interpret=True)
     dev = codec._device_tables()
+    offsets = (np.cumsum(chunk_bits) - chunk_bits).astype(np.int32)
+    W = 128  # every chunk of this stream spans far fewer words
     raw = np.frombuffer(stream, np.uint8)
-    padded = np.zeros(raw.size + (-raw.size) % 4 + 8, np.uint8)
+    padded = np.zeros(4 * (int(offsets.max() >> 5) + W), np.uint8)
     padded[: raw.size] = raw
     words = padded.view(">u4").astype(np.uint32).view(np.int32)
-    ends = np.cumsum(chunk_bits)
-    offsets = (ends - chunk_bits).astype(np.int32)
+    win = words[(offsets >> 5)[:, None] + np.arange(W)]
     C = chunk_bits.size
-    counts = np.full(C, cs, np.int32)
+    counts = np.full((C, 1), cs, np.int32)
     counts[-1] = codes.size - cs * (C - 1)
-    tables = [jnp.asarray(dev[key]) for key in
-              ("lut_count", "lut_bits", "lut_ids", "cw_map", "order",
-               "len_sorted")]
-    ids_a = ops.huffman_decode_op(
-        jnp.asarray(words), jnp.asarray(offsets), jnp.asarray(counts),
-        *tables, chunk_size=cs, k=dev["k"], use_pallas=True, interpret=True)
-    ids_b = ref.huffman_decode_ref(
-        jnp.asarray(words), jnp.asarray(offsets), jnp.asarray(counts),
-        *tables, chunk_size=cs, k=dev["k"])
+    args = [jnp.asarray(a) for a in (win, (offsets & 31)[:, None], counts,
+                                     dev["lut"], dev["cw_map"], dev["order"],
+                                     dev["len_sorted"])]
+    kw = dict(chunk_size=cs, k=_LUT_BITS, n_ids=dev["n_ids"])
+    ids_a = ops.huffman_decode_op(*args, **kw, use_pallas=True, interpret=True)
+    ids_b = ref.huffman_decode_ref(*args, **kw)
     np.testing.assert_array_equal(np.asarray(ids_a), np.asarray(ids_b))
     flat = np.asarray(ids_a).reshape(-1)[: codes.size]
     np.testing.assert_array_equal(codec.alphabet[flat], codes)

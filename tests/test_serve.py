@@ -416,6 +416,23 @@ def test_daemon_etag_304_skips_decode(tmp_path, tiled_vol, full):
         assert m3["not_modified"] == 2 and m3["errors"] == 0
 
 
+def test_daemon_serves_integer_index_rois(tmp_path, tiled_vol, full):
+    """An integer index in the ROI drops that axis, like numpy: the daemon
+    serves it (the ETag path used to crash on it), and its tag differs from
+    the one-wide slice that reads the same voxels but keeps the axis."""
+    pool = VolumePool({"nyx": _gwtc_path(tmp_path, tiled_vol)},
+                      cache_bytes=8 << 20, mem_budget=8 << 20)
+    with RegionServer(pool) as srv:
+        arr, meta = fetch_region(srv.url, "nyx", "12,:,3:9")
+        np.testing.assert_array_equal(arr, full[12, :, 3:9])
+        neg, _ = fetch_region(srv.url, "nyx", "-1,5,:")
+        np.testing.assert_array_equal(neg, full[-1, 5, :])
+        wide, meta_wide = fetch_region(srv.url, "nyx", "12:13,:,3:9")
+        np.testing.assert_array_equal(wide, full[12:13, :, 3:9])
+        assert meta["etag"] != meta_wide["etag"]
+        assert fetch_json(srv.url, "/metrics")["errors"] == 0
+
+
 # ---------------------------------------------------------------------------
 # CLI: normalized exit codes (0 ok / 1 integrity / 2 usage) + serve
 # ---------------------------------------------------------------------------

@@ -251,6 +251,48 @@ def test_sharded_dispatch_multi_device_parity(vol):
     assert proc.stdout == art.to_bytes()
 
 
+def test_stream_ingest_pinned_device_matches_mesh(tmp_path):
+    """One process, 4 host devices: a streamed ingest over the 4-device tile
+    mesh and one pinned to a single device write the same container (the
+    CPU rehearsal of ``chip_smoke.py --chips 4``); pins do not nest."""
+    code = (
+        "import sys, numpy as np, jax\n"
+        "from repro import api\n"
+        "from repro.data import nyx_like_field\n"
+        "from repro.launch import sharding\n"
+        "assert len(sharding.tile_devices()) == 4\n"
+        "x = np.asarray(nyx_like_field((32, 32, 32), 'temperature', seed=4))\n"
+        "out = sys.argv[1]\n"
+        "r4 = api.compress_stream(x, out + '/a.gwtc', eb=1e-3, tile=(8, 8, 8),\n"
+        "                         mem_budget=256 << 10)\n"
+        "with sharding.pin_tile_devices(jax.devices()[:1]) as devs:\n"
+        "    assert sharding.tile_devices() == devs and len(devs) == 1\n"
+        "    assert sharding.tile_mesh().devices.size == 1\n"
+        "    try:\n"
+        "        with sharding.pin_tile_devices(jax.devices()):\n"
+        "            raise SystemExit('nested pin accepted')\n"
+        "    except RuntimeError:\n"
+        "        pass\n"
+        "    r1 = api.compress_stream(x, out + '/b.gwtc', eb=1e-3, tile=(8, 8, 8),\n"
+        "                             mem_budget=256 << 10)\n"
+        "assert len(sharding.tile_devices()) == 4\n"
+        "assert r4.batch_tiles % 4 == 0 and r4.n_batches > 1\n"
+        "a = open(out + '/a.gwtc', 'rb').read()\n"
+        "assert a == open(out + '/b.gwtc', 'rb').read()\n"
+        "print(len(a))\n"
+    )
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(ROOT, "src"), env.get("PYTHONPATH", "")]).rstrip(os.pathsep)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                        " --xla_force_host_platform_device_count=4").strip()
+    proc = subprocess.run([sys.executable, "-c", code, str(tmp_path)], env=env,
+                          capture_output=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr.decode()
+    assert int(proc.stdout) > 0
+
+
 # -- GWLZ over the tile grid ---------------------------------------------------
 
 
