@@ -1,0 +1,6 @@
+"""Predictor + quantize kernel's share of its roofline over the window's ingests (%)."""
+from bench import readers
+
+
+def read(ctx):
+    return readers.kernel_roofline(ctx, "ingest", "lorenzo_quant")

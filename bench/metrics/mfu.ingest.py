@@ -1,0 +1,6 @@
+"""Least time the chip could take for the window's required ingest work, over the window (%)."""
+from bench import readers
+
+
+def read(ctx):
+    return readers.mfu(ctx, "ingest")
