@@ -47,6 +47,7 @@ from collections.abc import Iterator, Mapping
 
 import numpy as np
 
+from repro import obs
 from repro.core.pipeline import GWLZ, GWLZStats
 from repro.core.trainer import GWLZTrainConfig
 from repro.errors import CorruptContainerError, CorruptLaneError, IntegrityError
@@ -320,7 +321,10 @@ class CompressedVolume:
         mutate."""
         self._ensure_open()
         if self._cache is None:
-            self._cache = np.asarray(self.pipeline.decode(self.artifact))
+            with obs.span("gwlz.decode"):
+                out = self.pipeline.decode(self.artifact)
+                with obs.span("gwlz.decode.fetch"):
+                    self._cache = np.asarray(out)
             self._cache.setflags(write=False)
             self.stats.record(decoded=self.stats.tiles_total)
             self._sync_quarantine()

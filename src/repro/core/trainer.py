@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import enhancer, grouping
 from repro.optim import AdamWConfig
 from repro.optim import adamw
@@ -138,22 +139,24 @@ def train_enhancers(
     per-group training loss (Fig. 5 reproduction).
     """
     G = cfg.n_groups
-    xs = _as_slices(jnp.asarray(xprime, jnp.float32), cfg.slice_axis)
-    rs = _as_slices(jnp.asarray(residual, jnp.float32), cfg.slice_axis)
-    n_slices = xs.shape[0]
+    with obs.span("gwlz.train.groups"):
+        xs = _as_slices(jnp.asarray(xprime, jnp.float32), cfg.slice_axis)
+        rs = _as_slices(jnp.asarray(residual, jnp.float32), cfg.slice_axis)
+        n_slices = xs.shape[0]
 
-    edges = grouping.compute_edges(xs, G, cfg.strategy)
-    ids = grouping.assign_groups(xs, edges)
-    rscale = _per_group_scale(rs, ids, G)
-    counts = jnp.zeros(G).at[ids.ravel()].add(1.0)
-    rscale = jnp.where(counts >= cfg.min_group_pixels, rscale, 0.0)
+        edges = grouping.compute_edges(xs, G, cfg.strategy)
+        ids = grouping.assign_groups(xs, edges)
+        rscale = _per_group_scale(rs, ids, G)
+        counts = jnp.zeros(G).at[ids.ravel()].add(1.0)
+        rscale = jnp.where(counts >= cfg.min_group_pixels, rscale, 0.0)
 
-    key = jax.random.PRNGKey(cfg.seed)
-    pkeys = jax.random.split(key, G)
-    params = jax.vmap(lambda k: enhancer.init_params(k, cfg.channels))(pkeys)
-    bn_state = jax.vmap(lambda _: enhancer.init_state(cfg.channels))(jnp.arange(G))
-    adam_cfg = AdamWConfig()
-    opt_state = adamw.init(params, adam_cfg)
+        key = jax.random.PRNGKey(cfg.seed)
+        pkeys = jax.random.split(key, G)
+        params = jax.vmap(lambda k: enhancer.init_params(k, cfg.channels))(pkeys)
+        bn_state = jax.vmap(lambda _: enhancer.init_state(cfg.channels))(
+            jnp.arange(G))
+        adam_cfg = AdamWConfig()
+        opt_state = adamw.init(params, adam_cfg)
 
     bs = min(cfg.batch_size, n_slices)
     steps_per_epoch = max(n_slices // bs, 1)
@@ -166,14 +169,16 @@ def train_enhancers(
         order = rng.permutation(n_slices)
         ep_loss = np.zeros(G, np.float64)
         for s in range(steps_per_epoch):
-            idx = order[s * bs : (s + 1) * bs]
-            xb, rb, idsb = xs[idx], rs[idx], ids[idx]
-            lr = sched(gstep)
-            params, bn_state, opt_state, losses = train_step(
-                params, bn_state, opt_state, xb, rb, idsb, edges, rscale, lr,
-                n_groups=G, residual_learning=cfg.residual_learning, adam_cfg=adam_cfg,
-            )
-            ep_loss += np.asarray(losses, np.float64)
+            with obs.span("gwlz.train.step"):
+                idx = order[s * bs : (s + 1) * bs]
+                xb, rb, idsb = xs[idx], rs[idx], ids[idx]
+                lr = sched(gstep)
+                params, bn_state, opt_state, losses = train_step(
+                    params, bn_state, opt_state, xb, rb, idsb, edges, rscale, lr,
+                    n_groups=G, residual_learning=cfg.residual_learning,
+                    adam_cfg=adam_cfg,
+                )
+                ep_loss += np.asarray(losses, np.float64)
             gstep += 1
         history["loss"][epoch] = ep_loss / steps_per_epoch
         history["lr"][epoch] = float(sched(gstep - 1))
@@ -181,11 +186,14 @@ def train_enhancers(
             callback(epoch, history["loss"][epoch])
     # Replace running BN stats with exact full-volume statistics (the data we
     # will enhance is exactly the data we trained on — see _bn_calibrate).
-    bn_state = _bn_calibrate(params, xs, ids, edges, n_groups=G)
+    with obs.span("gwlz.train.calibrate"):
+        bn_state = _bn_calibrate(params, xs, ids, edges, n_groups=G)
     if cfg.gate_groups and cfg.residual_learning:
-        gate = _gate_groups(params, bn_state, xs, rs, ids, edges, rscale, n_groups=G)
-        rscale = rscale * gate
-        history["gate"] = np.asarray(gate)
+        with obs.span("gwlz.train.gate"):
+            gate = _gate_groups(params, bn_state, xs, rs, ids, edges, rscale,
+                                n_groups=G)
+            rscale = rscale * gate
+            history["gate"] = np.asarray(gate)
     model = GWLZModel(params=params, bn_state=bn_state, edges=edges, rscale=rscale, cfg=cfg)
     return model, history
 
@@ -286,9 +294,11 @@ def train_enhancers_streamed(
     residuals through the group edges), so a uniform sample trains the same
     estimator the full stack would — just with sampling noise bounded by
     the reservoir size."""
-    recon, resid = reservoir.stacks()
-    return train_enhancers_tiled(jnp.asarray(recon), jnp.asarray(resid), cfg,
-                                 callback=callback)
+    with obs.span("gwlz.train"):
+        with obs.span("gwlz.train.stage", reservoir.nbytes):
+            recon, resid = reservoir.stacks()
+            recon, resid = jnp.asarray(recon), jnp.asarray(resid)
+        return train_enhancers_tiled(recon, resid, cfg, callback=callback)
 
 
 # Pixels per step of the whole-training-set passes below (BN calibration and

@@ -27,15 +27,16 @@ unlinking the work done so far.
 """
 from __future__ import annotations
 
+import contextvars
 import os
 import resource
 import threading
-import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro import obs
 from repro.exec.plan import StreamPlan, plan_stream
 from repro.exec.sources import TileSource, as_source, value_range
 from repro.exec.writer import GWTCWriter
@@ -85,14 +86,17 @@ class StreamReport:
     failed_batches: tuple[int, ...] = field(default_factory=tuple)
     resumed_batches: int = 0
     # entropy-stage accounting: whether lane packing ran in the device stage
-    # (Pallas Huffman kernels) and total wall time the host stage spent —
-    # with device entropy the host stage shrinks to container append+commit
+    # (Pallas Huffman kernels) and the writer thread's wall time (the
+    # ``gwlz.ingest.append`` span) — with device entropy that is container
+    # append+commit only, and lane coding runs on the caller's thread
     host_stage_s: float = 0.0
     entropy_device: bool = False
-    # compile accounting: how many FRESH device programs this stream forced
-    # (the plan's uniform batch width means at most one encode program per
-    # stream geometry; 0 = fully warm, via tiled.register_program_key)
+    # backend compiles this stream ran (0 = every program was already
+    # loaded in this process)
     programs_compiled: int = 0
+    # (count, seconds, bytes) per span name (``repro.obs``) over the stream,
+    # from the caller's thread and the writer thread
+    stages: dict = field(default_factory=dict)
 
     @property
     def peak_over_budget(self) -> float:
@@ -185,104 +189,90 @@ def stream_compress(
 
     from repro.sz.entropy import _accel_default
 
-    retry = retry if retry is not None else RetryPolicy()
-    src = as_source(source, shape=shape)
-    tile = normalize_tile(tile, len(src.shape))
-    eb = _resolve_eb_streaming(src, rel_eb, abs_eb)
-    pred = get_predictor(predictor)
-    levels = pred.plan(tile, max_levels)
-    # device entropy moves lane packing into the device stage, so the host
-    # stage shrinks to container append + commit (same auto-detect rule as
-    # the entropy layer; bytes are bit-identical either way)
-    device_entropy = _accel_default() if use_pallas is None else bool(use_pallas)
-    plan = plan_stream(src.shape, tile, mem_budget, predictor=predictor,
-                       levels=levels, device_entropy=device_entropy)
-    # the plan guarantees one uniform device-batch width (the short final run
-    # is padded), so this stream's encode is exactly one compiled program —
-    # register its identity so StreamReport can say whether it was fresh
-    from repro.launch.sharding import tile_devices
-    from repro.sz.tiled import register_program_key
+    with obs.collect() as col, obs.span("gwlz.ingest"):
+        retry = retry if retry is not None else RetryPolicy()
+        src = as_source(source, shape=shape)
+        tile = normalize_tile(tile, len(src.shape))
+        eb = _resolve_eb_streaming(src, rel_eb, abs_eb)
+        pred = get_predictor(predictor)
+        levels = pred.plan(tile, max_levels)
+        # device entropy moves lane packing into the device stage, so the host
+        # stage shrinks to container append + commit (same auto-detect rule as
+        # the entropy layer; bytes are bit-identical either way)
+        device_entropy = _accel_default() if use_pallas is None else bool(use_pallas)
+        plan = plan_stream(src.shape, tile, mem_budget, predictor=predictor,
+                           levels=levels, device_entropy=device_entropy)
+        want = (plan.shape, plan.tile, eb, backend, predictor, order, levels)
 
-    programs_compiled = int(register_program_key(
-        ("stream-encode", predictor, tuple(plan.tile), int(plan.batch_tiles),
-         order, int(levels), bool(device_entropy), len(tile_devices()))))
-    want = (plan.shape, plan.tile, eb, backend, predictor, order, levels)
+        start_tile, resumed_batches = 0, 0
+        if resume:
+            if enhance:
+                raise ValueError(
+                    "resume=True cannot train enhancers: the reservoir would "
+                    "sample only the re-streamed batches, so the attached model "
+                    "(and the container bytes) would depend on where the "
+                    "interruption fell — re-run without resume to enhance")
+            if isinstance(dest, GWTCWriter) or hasattr(dest, "write"):
+                raise ValueError("resume=True needs a path destination "
+                                 "(the commit journal lives next to the file)")
+            writer, path = GWTCWriter.resume(dest), str(dest)
+            aligned = plan.resume_point(writer.committed_lanes)
+            if aligned != writer.committed_lanes:
+                writer.truncate_lanes(aligned)  # mid-batch commit: redo the batch
+            start_tile = aligned
+            resumed_batches = start_tile // plan.batch_tiles
+        elif isinstance(dest, GWTCWriter):
+            # a pre-made writer already wrote its header; every header field must
+            # agree with how the lanes will actually be encoded, or the container
+            # would self-describe a decode that does not match its bytes
+            writer, path = dest, None
+        else:
+            path = None if hasattr(dest, "write") else str(dest)
+            writer = GWTCWriter(dest, shape=plan.shape, tile=plan.tile, eb_abs=eb,
+                                backend=backend, predictor=predictor, order=order,
+                                levels=levels)
+        if resume or isinstance(dest, GWTCWriter):
+            wrote = (writer.shape, writer.tile, writer.eb_abs, writer.backend,
+                     writer.predictor, writer.order, writer.levels)
+            if wrote != want:
+                if resume:
+                    writer.abort()
+                raise ValueError(
+                    f"writer header {wrote} does not match the encode settings "
+                    f"{want} (shape, tile, eb_abs, backend, predictor, order, "
+                    "levels must agree)")
 
-    start_tile, resumed_batches = 0, 0
-    if resume:
+        reservoir = None
         if enhance:
-            raise ValueError(
-                "resume=True cannot train enhancers: the reservoir would "
-                "sample only the re-streamed batches, so the attached model "
-                "(and the container bytes) would depend on where the "
-                "interruption fell — re-run without resume to enhance")
-        if isinstance(dest, GWTCWriter) or hasattr(dest, "write"):
-            raise ValueError("resume=True needs a path destination "
-                             "(the commit journal lives next to the file)")
-        writer, path = GWTCWriter.resume(dest), str(dest)
-        aligned = plan.resume_point(writer.committed_lanes)
-        if aligned != writer.committed_lanes:
-            writer.truncate_lanes(aligned)  # mid-batch commit: redo the batch
-        start_tile = aligned
-        resumed_batches = start_tile // plan.batch_tiles
-    elif isinstance(dest, GWTCWriter):
-        # a pre-made writer already wrote its header; every header field must
-        # agree with how the lanes will actually be encoded, or the container
-        # would self-describe a decode that does not match its bytes
-        writer, path = dest, None
-    else:
-        path = None if hasattr(dest, "write") else str(dest)
-        writer = GWTCWriter(dest, shape=plan.shape, tile=plan.tile, eb_abs=eb,
-                            backend=backend, predictor=predictor, order=order,
-                            levels=levels)
-    if resume or isinstance(dest, GWTCWriter):
-        wrote = (writer.shape, writer.tile, writer.eb_abs, writer.backend,
-                 writer.predictor, writer.order, writer.levels)
-        if wrote != want:
-            if resume:
-                writer.abort()
-            raise ValueError(
-                f"writer header {wrote} does not match the encode settings "
-                f"{want} (shape, tile, eb_abs, backend, predictor, order, "
-                "levels must agree)")
+            from repro.core.trainer import GWLZTrainConfig, TileReservoir
 
-    reservoir = None
-    if enhance:
-        from repro.core.trainer import GWLZTrainConfig, TileReservoir
+            cfg = enhance if isinstance(enhance, GWLZTrainConfig) else GWLZTrainConfig()
+            if reservoir_tiles is None:
+                pair_bytes = 8 * int(np.prod(tile))  # f32 recon + f32 residual
+                reservoir_tiles = max(4, (mem_budget // 4) // pair_bytes)
+            reservoir = TileReservoir(int(reservoir_tiles), seed=cfg.seed)
 
-        cfg = enhance if isinstance(enhance, GWLZTrainConfig) else GWLZTrainConfig()
-        if reservoir_tiles is None:
-            pair_bytes = 8 * int(np.prod(tile))  # f32 recon + f32 residual
-            reservoir_tiles = max(4, (mem_budget // 4) // pair_bytes)
-        reservoir = TileReservoir(int(reservoir_tiles), seed=cfg.seed)
+        mem = MemTracker()
+        pool = ThreadPoolExecutor(1, thread_name_prefix="gwtc-host")
+        pending = None
+        # retry accounting, shared between the main thread (device stage) and
+        # the host worker — on_retry callbacks from both land here
+        fault_lock = threading.Lock()
+        retries = 0
+        failed_batches: set[int] = set()
 
-    mem = MemTracker()
-    pool = ThreadPoolExecutor(1, thread_name_prefix="gwtc-host")
-    pending = None
-    # retry accounting, shared between the main thread (device stage) and
-    # the host worker — on_retry callbacks from both land here
-    fault_lock = threading.Lock()
-    retries = 0
-    failed_batches: set[int] = set()
+        def note_retry(bidx: int):
+            def cb(_exc, _attempt):
+                nonlocal retries
+                with fault_lock:
+                    retries += 1
+                    failed_batches.add(bidx)
+            return cb
 
-    def note_retry(bidx: int):
-        def cb(_exc, _attempt):
-            nonlocal retries
-            with fault_lock:
-                retries += 1
-                failed_batches.add(bidx)
-        return cb
-
-    host_time_lock = threading.Lock()
-    host_stage_s = 0.0
-
-    def host_stage(payload_np, ids, bidx: int, nbytes_held: int,
-                   blobs=None) -> None:
-        """``blobs`` set means the device stage already packed the lanes —
-        the host stage is pure container append + commit."""
-        nonlocal host_stage_s
-        t0 = time.perf_counter()
-        try:
+        def host_stage(payload_np, ids, bidx: int, nbytes_held: int,
+                       blobs=None) -> None:
+            """``blobs`` set means the device stage already packed the lanes —
+            the host stage is pure container append + commit."""
             def append_batch():
                 if writer.can_rollback:
                     # drop any half-appended lanes from a previous attempt so
@@ -296,117 +286,133 @@ def stream_compress(
                         else pred.lane_bytes(payload_np, j, backend))
                 writer.commit()
 
-            if writer.can_rollback:
-                retry.run(append_batch, on_retry=note_retry(bidx))
-            else:
-                append_batch()  # shared sink: no safe replay, fail fast
-        finally:
-            mem.sub(nbytes_held)
-            with host_time_lock:
-                host_stage_s += time.perf_counter() - t0
+            try:
+                with obs.span("gwlz.ingest.append"):
+                    if writer.can_rollback:
+                        retry.run(append_batch, on_retry=note_retry(bidx))
+                    else:
+                        append_batch()  # shared sink: no safe replay, fail fast
+            finally:
+                mem.sub(nbytes_held)
 
-    try:
-        for bidx, run in enumerate(plan.batches(start_tile),
-                                   start=resumed_batches):
-            ids = list(run)
-            # the batch read stays OUTSIDE the retry scope: sources are
-            # forward-only streams, a re-read is not generally possible
-            batch = _read_batch(src, ids, plan)
-            # same f32-overflow guard as quantizer.resolve_eb, applied to the
-            # data actually seen (an abs_eb stream takes no range prepass)
-            max_q = float(np.abs(batch[: len(ids)]).max()) / (2.0 * eb)
-            if max_q >= 2**30:
-                raise ValueError(
-                    f"eb={eb:g} too small for data magnitude "
-                    f"(q={max_q:.3g} >= 2^30)")
-            mem.add(batch.nbytes)
+        try:
+            for bidx, run in enumerate(plan.batches(start_tile),
+                                       start=resumed_batches):
+                ids = list(run)
+                with obs.span("gwlz.ingest.read"):
+                    # the batch read stays OUTSIDE the retry scope: sources are
+                    # forward-only streams, a re-read is not generally possible
+                    batch = _read_batch(src, ids, plan)
+                    # same f32-overflow guard as quantizer.resolve_eb, applied
+                    # to the data actually seen (an abs_eb stream takes no
+                    # range prepass)
+                    max_q = float(np.abs(batch[: len(ids)]).max()) / (2.0 * eb)
+                if max_q >= 2**30:
+                    raise ValueError(
+                        f"eb={eb:g} too small for data magnitude "
+                        f"(q={max_q:.3g} >= 2^30)")
+                mem.add(batch.nbytes)
 
-            def encode():
-                if injector is not None:
-                    injector.maybe_fail(bidx)
-                return pred.encode_tiles(batch, eb, order=order,
-                                         levels=levels, use_pallas=use_pallas)
+                def encode():
+                    if injector is not None:
+                        injector.maybe_fail(bidx)
+                    return pred.encode_tiles(batch, eb, order=order,
+                                             levels=levels, use_pallas=use_pallas)
 
-            payload, recon = retry.run(encode, on_retry=note_retry(bidx))
-            payload_np = jax.tree.map(np.asarray, payload)
-            held = sum(leaf.nbytes for leaf in jax.tree.leaves(payload_np))
-            blobs = None
-            if device_entropy:
-                # device stage emits the packed lane bytes directly (Pallas
-                # encode kernel); only the lanes actually written, not the
-                # batch's repeat padding
-                blobs = pred.lane_bytes_batch(payload_np, len(ids), backend,
-                                              use_pallas=True)
-                held += sum(len(b) for b in blobs)
-            mem.add(held)
-            if reservoir is not None:
-                recon_np = np.asarray(recon)[: len(ids)]
-                mem.add(recon_np.nbytes)
-                grew = reservoir.offer(recon_np, batch[: len(ids)] - recon_np)
-                mem.add(grew)
-                mem.sub(recon_np.nbytes)
-            del recon
-            mem.sub(batch.nbytes)
-            del batch
+                with obs.span("gwlz.ingest.encode"):
+                    payload, recon = retry.run(encode, on_retry=note_retry(bidx))
+                with obs.span("gwlz.ingest.fetch", sum(
+                        leaf.nbytes for leaf in jax.tree.leaves(payload))):
+                    payload_np = jax.tree.map(np.asarray, payload)
+                held = sum(leaf.nbytes for leaf in jax.tree.leaves(payload_np))
+                blobs = None
+                if device_entropy:
+                    # device stage emits the packed lane bytes directly (Pallas
+                    # encode kernel); only the lanes actually written, not the
+                    # batch's repeat padding
+                    with obs.span("gwlz.ingest.lanes"):
+                        blobs = pred.lane_bytes_batch(payload_np, len(ids),
+                                                      backend, use_pallas=True)
+                    held += sum(len(b) for b in blobs)
+                mem.add(held)
+                if reservoir is not None:
+                    with obs.span("gwlz.ingest.reservoir"):
+                        recon_np = np.asarray(recon)[: len(ids)]
+                        mem.add(recon_np.nbytes)
+                        grew = reservoir.offer(recon_np,
+                                               batch[: len(ids)] - recon_np)
+                    mem.add(grew)
+                    mem.sub(recon_np.nbytes)
+                del recon
+                mem.sub(batch.nbytes)
+                del batch
+                if pending is not None:
+                    with obs.span("gwlz.ingest.wait_writer"):
+                        pending.result()  # cap in-flight host work at one batch
+                # the writer thread runs in a copy of this context, so its
+                # spans reach this stream's collector
+                pending = pool.submit(contextvars.copy_context().run, host_stage,
+                                      payload_np, ids, bidx, held, blobs)
+                del payload, payload_np, blobs
             if pending is not None:
-                pending.result()  # cap in-flight host work at one batch
-            pending = pool.submit(host_stage, payload_np, ids, bidx, held,
-                                  blobs)
-            del payload, payload_np, blobs
-        if pending is not None:
-            pending.result()
-            pending = None
+                with obs.span("gwlz.ingest.wait_writer"):
+                    pending.result()
+                pending = None
 
-        enhanced = False
-        if reservoir is not None and len(reservoir):
-            from repro.core.pipeline import serialize_model
-            from repro.core.trainer import train_enhancers_streamed
+            enhanced = False
+            if reservoir is not None and len(reservoir):
+                from repro.core.pipeline import serialize_model
+                from repro.core.trainer import train_enhancers_streamed
 
-            model, _hist = train_enhancers_streamed(reservoir, cfg)
-            writer.extras["gwlz"] = serialize_model(model)
-            enhanced = True
-        nbytes = writer.finalize()
-    except BaseException:
-        if pending is not None:  # drain the worker before touching the sink
-            try:
-                pending.result()
-            # the worker can only fail the ways the append path fails; a
-            # propagating exception here would mask the original error
-            except (OSError, RuntimeError, ValueError):
-                pass
-            pending = None
-        if not isinstance(dest, GWTCWriter):
-            journaled = writer._journal_path is not None
-            writer.abort()  # close the fd; no footer = detectably truncated
-            if path is not None and not journaled:
+                model, _hist = train_enhancers_streamed(reservoir, cfg)
+                with obs.span("gwlz.train.serialize"):
+                    writer.extras["gwlz"] = serialize_model(model)
+                enhanced = True
+            with obs.span("gwlz.ingest.finalize"):
+                nbytes = writer.finalize()
+        except BaseException:
+            if pending is not None:  # drain the worker before touching the sink
                 try:
-                    os.unlink(path)  # don't leave a garbage container behind
-                except OSError:
+                    pending.result()
+                # the worker can only fail the ways the append path fails; a
+                # propagating exception here would mask the original error
+                except (OSError, RuntimeError, ValueError):
                     pass
-            # journaled path dests keep the partial container + journal on
-            # disk: that pair is exactly what resume=True needs
-        raise
-    finally:
-        if pending is not None:  # a failed batch: drain the worker first
-            try:
-                pending.result()
-            except (OSError, RuntimeError, ValueError):
-                pass
-        pool.shutdown(wait=True)
-        src.close()
+                pending = None
+            if not isinstance(dest, GWTCWriter):
+                journaled = writer._journal_path is not None
+                writer.abort()  # close the fd; no footer = detectably truncated
+                if path is not None and not journaled:
+                    try:
+                        os.unlink(path)  # don't leave a garbage container behind
+                    except OSError:
+                        pass
+                # journaled path dests keep the partial container + journal on
+                # disk: that pair is exactly what resume=True needs
+            raise
+        finally:
+            if pending is not None:  # a failed batch: drain the worker first
+                try:
+                    pending.result()
+                except (OSError, RuntimeError, ValueError):
+                    pass
+            pool.shutdown(wait=True)
+            src.close()
 
-    return StreamReport(
-        path=path, shape=plan.shape, tile=plan.tile, n_tiles=plan.n_tiles,
-        n_batches=plan.n_batches, batch_tiles=plan.batch_tiles, nbytes=nbytes,
-        eb_abs=eb, predictor=predictor, backend=backend,
-        mem_budget=int(mem_budget), peak_tracked_bytes=mem.peak,
-        ru_maxrss_kb=int(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss),
-        enhanced=enhanced,
-        reservoir_tiles=len(reservoir) if reservoir is not None else 0,
-        retries=retries,
-        failed_batches=tuple(sorted(failed_batches)),
-        resumed_batches=resumed_batches,
-        host_stage_s=host_stage_s,
-        entropy_device=device_entropy,
-        programs_compiled=programs_compiled,
-    )
+        report = StreamReport(
+            path=path, shape=plan.shape, tile=plan.tile, n_tiles=plan.n_tiles,
+            n_batches=plan.n_batches, batch_tiles=plan.batch_tiles, nbytes=nbytes,
+            eb_abs=eb, predictor=predictor, backend=backend,
+            mem_budget=int(mem_budget), peak_tracked_bytes=mem.peak,
+            ru_maxrss_kb=int(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss),
+            enhanced=enhanced,
+            reservoir_tiles=len(reservoir) if reservoir is not None else 0,
+            retries=retries,
+            failed_batches=tuple(sorted(failed_batches)),
+            resumed_batches=resumed_batches,
+            entropy_device=device_entropy,
+        )
+    report.stages = col.stages()
+    report.host_stage_s = report.stages.get("gwlz.ingest.append", (0, 0.0, 0))[1]
+    report.programs_compiled = col.compiles
+    return report

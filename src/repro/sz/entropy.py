@@ -29,6 +29,7 @@ from typing import NamedTuple
 
 import numpy as np
 
+from repro import obs
 from repro.sz.artifact import ENTROPY_MAGIC
 
 _MAGIC = ENTROPY_MAGIC
@@ -172,8 +173,9 @@ def _accel_hist(flat: np.ndarray, lo: int, span: int) -> np.ndarray:
 
     from repro.kernels import ops
 
-    shifted = jnp.asarray((flat.astype(np.int64) - lo).astype(np.int32))
-    return np.asarray(ops.symbol_hist_op(shifted, n_bins=span), np.int64)
+    with obs.span("gwlz.entropy.hist", 4 * flat.size):
+        shifted = jnp.asarray((flat.astype(np.int64) - lo).astype(np.int32))
+        return np.asarray(ops.symbol_hist_op(shifted, n_bins=span), np.int64)
 
 
 def _splice_chunks(local: np.ndarray, chunk_bits: np.ndarray) -> tuple[bytes, int]:
@@ -673,25 +675,27 @@ class HuffmanCodec:
 
         from repro.kernels import ops
 
-        # same one-shot fit-time remap contract as _encode_bits
-        inv = self.__dict__.pop("_inv", None)
-        if inv is None or inv.size != n:
-            inv = np.searchsorted(self.alphabet, flat)
-        C = -(-n // chunk_size)
-        pad = C * chunk_size - n
-        lens = self.lengths[inv].astype(np.int32)
-        cws = self.codes[inv].astype(np.uint32).view(np.int32)
-        if pad:
-            lens = np.concatenate([lens, np.zeros(pad, np.int32)])
-            cws = np.concatenate([cws, np.zeros(pad, np.int32)])
-        words, chunk_bits = ops.huffman_encode_op(
-            jnp.asarray(lens.reshape(C, chunk_size)),
-            jnp.asarray(cws.reshape(C, chunk_size)),
-            use_pallas=True, interpret=interpret)
-        stream, total = _splice_chunks(
-            np.asarray(words).view(np.uint32),
-            np.asarray(chunk_bits).astype(np.int64))
-        return stream, np.asarray(chunk_bits).astype(np.int64), total
+        with obs.span("gwlz.entropy.pack"):
+            # same one-shot fit-time remap contract as _encode_bits
+            inv = self.__dict__.pop("_inv", None)
+            if inv is None or inv.size != n:
+                inv = np.searchsorted(self.alphabet, flat)
+            C = -(-n // chunk_size)
+            pad = C * chunk_size - n
+            lens = self.lengths[inv].astype(np.int32)
+            cws = self.codes[inv].astype(np.uint32).view(np.int32)
+            if pad:
+                lens = np.concatenate([lens, np.zeros(pad, np.int32)])
+                cws = np.concatenate([cws, np.zeros(pad, np.int32)])
+            words, chunk_bits = ops.huffman_encode_op(
+                jnp.asarray(lens.reshape(C, chunk_size)),
+                jnp.asarray(cws.reshape(C, chunk_size)),
+                use_pallas=True, interpret=interpret)
+            words = np.asarray(words).view(np.uint32)
+            chunk_bits = np.asarray(chunk_bits).astype(np.int64)
+        with obs.span("gwlz.entropy.splice", words.nbytes):
+            stream, total = _splice_chunks(words, chunk_bits)
+        return stream, chunk_bits, total
 
     def decode_chunked_device(
         self,
@@ -739,29 +743,30 @@ class HuffmanCodec:
 
         from repro.kernels import ops
 
-        # one word window per chunk, from the word holding its first bit to
-        # one word past its last (the probe reads word pairs); the width
-        # rounds up to whole 128-lane rows, zeros past the stream
-        first = offsets >> 5
-        starts = offsets & 31
-        span = (starts + chunk_bits + 31) >> 5
-        W = -(-(int(span.max()) + 1) // 128) * 128
-        raw = np.frombuffer(stream, np.uint8)
-        padded = np.zeros(max(4 * (int(first.max()) + W), raw.size + 3) // 4 * 4,
-                          np.uint8)
-        padded[: raw.size] = raw
-        words = padded.view(">u4").astype(np.uint32).view(np.int32)
-        win = words[first[:, None] + np.arange(W)]
-        ids = ops.huffman_decode_op(
-            jnp.asarray(win), jnp.asarray(starts[:, None]),
-            jnp.asarray(counts[:, None]), jnp.asarray(dev["lut"]),
-            jnp.asarray(dev["cw_map"]), jnp.asarray(dev["order"]),
-            jnp.asarray(dev["len_sorted"]), chunk_size=chunk_size,
-            k=_LUT_BITS, n_ids=dev["n_ids"], use_pallas=True,
-            interpret=interpret)
-        # only the last selected chunk can be short, so row-major flatten +
-        # truncate is exactly the symbol stream
-        flat_ids = np.asarray(ids).reshape(-1)[:n_symbols]
+        with obs.span("gwlz.entropy.probe", len(stream)):
+            # one word window per chunk, from the word holding its first bit
+            # to one word past its last (the probe reads word pairs); the
+            # width rounds up to whole 128-lane rows, zeros past the stream
+            first = offsets >> 5
+            starts = offsets & 31
+            span = (starts + chunk_bits + 31) >> 5
+            W = -(-(int(span.max()) + 1) // 128) * 128
+            raw = np.frombuffer(stream, np.uint8)
+            padded = np.zeros(
+                max(4 * (int(first.max()) + W), raw.size + 3) // 4 * 4, np.uint8)
+            padded[: raw.size] = raw
+            words = padded.view(">u4").astype(np.uint32).view(np.int32)
+            win = words[first[:, None] + np.arange(W)]
+            ids = ops.huffman_decode_op(
+                jnp.asarray(win), jnp.asarray(starts[:, None]),
+                jnp.asarray(counts[:, None]), jnp.asarray(dev["lut"]),
+                jnp.asarray(dev["cw_map"]), jnp.asarray(dev["order"]),
+                jnp.asarray(dev["len_sorted"]), chunk_size=chunk_size,
+                k=_LUT_BITS, n_ids=dev["n_ids"], use_pallas=True,
+                interpret=interpret)
+            # only the last selected chunk can be short, so row-major
+            # flatten + truncate is exactly the symbol stream
+            flat_ids = np.asarray(ids).reshape(-1)[:n_symbols]
         return self.alphabet[flat_ids]
 
     # -- serialization --------------------------------------------------------
@@ -812,14 +817,15 @@ def encode_codes(
     if backend == "zlib":
         # int32 -> int16 when it fits (usual case): halves the zlib input
         if flat.size and abs(flat).max(initial=0) < 2**15:
-            payload = zlib.compress(flat.astype(np.int16).tobytes(), 6)
+            payload = _deflate(flat.astype(np.int16).tobytes())
             tag = b"z2"
         else:
-            payload = zlib.compress(flat.tobytes(), 6)
+            payload = _deflate(flat.tobytes())
             tag = b"z4"
         return _MAGIC + tag + struct.pack("<Q", flat.size) + payload
     if backend in ("huffman", "huffman+zlib"):
-        codec = HuffmanCodec.fit(flat, use_accel=use_accel)
+        with obs.span("gwlz.entropy.fit"):
+            codec = HuffmanCodec.fit(flat, use_accel=use_accel)
         cs = int(chunk_size) if chunk_size else DEFAULT_CHUNK
         n = flat.size
         n_chunks = -(-n // cs) if n else 0
@@ -839,7 +845,7 @@ def encode_codes(
         # chunk table + bit stream travel together so zlib sees both
         payload = chunk_bits.astype(_chunk_bits_dtype(cs)).tobytes() + stream
         if backend == "huffman+zlib":
-            payload = zlib.compress(payload, 6)
+            payload = _deflate(payload)
             tag = b"hZ"
         else:
             tag = b"hc"
@@ -853,6 +859,20 @@ def encode_codes(
             + payload
         )
     raise ValueError(f"unknown entropy backend {backend!r}")
+
+
+def _deflate(raw: bytes) -> bytes:
+    """zlib level 6, as every deflating backend writes it, with the bytes in
+    and out counted (``gwlz.entropy.deflate``/``gwlz.entropy.deflate_out``)."""
+    with obs.span("gwlz.entropy.deflate", len(raw)):
+        out = zlib.compress(raw, 6)
+    obs.count("gwlz.entropy.deflate_out", len(out))
+    return out
+
+
+def _inflate(blob: bytes) -> bytes:
+    with obs.span("gwlz.entropy.inflate", len(blob)):
+        return zlib.decompress(blob)
 
 
 def encode_codes_legacy(codes: np.ndarray, backend: str = "huffman+zlib") -> bytes:
@@ -900,7 +920,7 @@ def decode_codes(blob: bytes, shape: tuple[int, ...], *, workers: int | None = N
     tag = blob[4:6]
     if tag in (b"z2", b"z4"):
         (n,) = struct.unpack_from("<Q", blob, 6)
-        raw = zlib.decompress(blob[14:])
+        raw = _inflate(blob[14:])
         dt = np.int16 if tag == b"z2" else np.int32
         return np.frombuffer(raw, dt).astype(np.int32).reshape(shape)
     if tag in (b"hc", b"hZ"):
@@ -912,7 +932,7 @@ def decode_codes(blob: bytes, shape: tuple[int, ...], *, workers: int | None = N
         off += 8
         payload = blob[off:]
         if tag == b"hZ":
-            payload = zlib.decompress(payload)
+            payload = _inflate(payload)
         cb_dtype = _chunk_bits_dtype(cs)
         chunk_bits = np.frombuffer(payload, cb_dtype, n_chunks)
         stream = payload[np.dtype(cb_dtype).itemsize * n_chunks :]
@@ -963,7 +983,7 @@ def decode_codes_range(blob: bytes, lo: int, hi: int, *, workers: int | None = N
         off += 8
         payload = blob[off:]
         if tag == b"hZ":
-            payload = zlib.decompress(payload)
+            payload = _inflate(payload)
         cb_dtype = _chunk_bits_dtype(cs)
         chunk_bits = np.frombuffer(payload, cb_dtype, n_chunks)
         stream = payload[np.dtype(cb_dtype).itemsize * n_chunks :]

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.errors import CorruptContainerError, CorruptLaneError
 from repro.sz import artifact as A
 from repro.sz.predictor import ORDER_IDS, ORDER_NAMES, PRED_IDS, PRED_NAMES, get_predictor
@@ -235,9 +236,8 @@ def bucket_chunks(n: int, bucket_cap: int | None = None) -> list[int]:
 
 
 def register_program_key(key) -> bool:
-    """Record one compiled-program identity; True the first time (a compile),
-    False on a warm hit.  The streaming executor registers its encode
-    program here so StreamReport can report compile counts the same way."""
+    """Record one compiled-program identity in ``dispatch_stats()``; True the
+    first time (a compile), False on a warm hit."""
     with _DISPATCH_LOCK:
         fresh = key not in _PROGRAM_KEYS
         if fresh:
@@ -757,23 +757,27 @@ def decode_lanes(
     blobs = [artifact.tile_blobs[i] for i in lane_ids]
     good = [j for j, (i, b) in enumerate(zip(lane_ids, blobs))
             if _check_lane(artifact, i, b)]
-    items = _map_lanes(
-        lambda b: pred.parse_lane(b, tile=artifact.tile, levels=artifact.levels,
-                                  use_pallas=use_pallas),
-        [blobs[j] for j in good], workers)
+    with obs.span("gwlz.decode.lanes"):
+        items = _map_lanes(
+            lambda b: pred.parse_lane(b, tile=artifact.tile,
+                                      levels=artifact.levels,
+                                      use_pallas=use_pallas),
+            [blobs[j] for j in good], workers)
     with _STATS_LOCK:
         DECODE_STATS["tiles_decoded"] = len(good)
         DECODE_STATS["tiles_total"] = artifact.n_tiles
     if good:
-        payload = {k: jnp.asarray(np.stack([it[k] for it in items]))
-                   for k in items[0]}
+        with obs.span("gwlz.decode.upload"):
+            payload = {k: jnp.asarray(np.stack([it[k] for it in items]))
+                       for k in items[0]}
         key = pred.decode_program_key(tile=artifact.tile, order=artifact.order,
                                       levels=artifact.levels)
-        recon = dispatch_bucketed(
-            lambda p: pred.decode_tiles(
-                p, artifact.eb_abs, tile=artifact.tile,
-                order=artifact.order, levels=artifact.levels),
-            payload, len(good), key=key, bucket_cap=bucket_cap)
+        with obs.span("gwlz.decode.reconstruct"):
+            recon = dispatch_bucketed(
+                lambda p: pred.decode_tiles(
+                    p, artifact.eb_abs, tile=artifact.tile,
+                    order=artifact.order, levels=artifact.levels),
+                payload, len(good), key=key, bucket_cap=bucket_cap)
     bad_mask = np.zeros(len(lane_ids), bool)
     if len(good) < len(lane_ids):
         good_set = set(good)
@@ -813,11 +817,13 @@ def decompress_tiled(
                                  workers=workers, with_mask=True,
                                  use_pallas=use_pallas, bucket_cap=bucket_cap)
     if tile_transform is not None:
-        recon = apply_tile_transform(tile_transform, recon,
-                                     bucket_cap=bucket_cap)
-        recon = _refill_quarantined(recon, bad, artifact.fill_value)
-    out = stitch_tiles(recon, artifact.grid)
-    return out[tuple(slice(0, d) for d in artifact.shape)]
+        with obs.span("gwlz.decode.enhance"):
+            recon = apply_tile_transform(tile_transform, recon,
+                                         bucket_cap=bucket_cap)
+            recon = _refill_quarantined(recon, bad, artifact.fill_value)
+    with obs.span("gwlz.decode.stitch"):
+        out = stitch_tiles(recon, artifact.grid)
+        return out[tuple(slice(0, d) for d in artifact.shape)]
 
 
 def _refill_quarantined(recon, bad_mask: np.ndarray, fill_value: float):
