@@ -397,13 +397,16 @@ def main() -> None:
 
     import jax
 
-    p = E.init_params(jax.random.PRNGKey(0))
-    s = E.init_state()
+    edges = jnp.linspace(float(x.min()), float(x.max()) + 1, 21)
+    p = jax.vmap(E.init_params)(jax.random.split(jax.random.PRNGKey(0), 20))
+    s = jax.vmap(lambda _: E.init_state())(jnp.arange(20))
     slices = x[:16]
-    _, us = timed(lambda: ops.enhancer_fused_op(slices, p, s, use_pallas=False).block_until_ready(), repeats=3)
+    _, us = timed(lambda: ops.enhancer_fused_op(
+        slices, p, s, edges, jnp.ones(20), jnp.float32(0.0), n_groups=20,
+        residual_learning=True, use_clamp=False, use_pallas=False).block_until_ready(),
+        repeats=3)
     emit("throughput/kernel/enhancer_ref", us, f"MBps={slices.size*4/us:.1f}")
 
-    edges = jnp.linspace(float(x.min()), float(x.max()) + 1, 21)
     n = (x.size // 128) * 128
     xf = x.ravel()[:n]
     _, us = timed(lambda: ops.group_hist_op(xf.reshape(-1, 128), edges, n_groups=20, use_pallas=False)[0].block_until_ready(), repeats=3)

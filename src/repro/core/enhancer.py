@@ -10,14 +10,12 @@ pytree with a leading G axis (vmap over models — DESIGN.md §3.3).
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 DEFAULT_CHANNELS = 9
-_BN_EPS = 1e-5
+BN_EPS = 1e-5
 _BN_MOMENTUM = 0.1
 
 
@@ -62,8 +60,9 @@ def _conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
 
     XLA CPU's conv *transpose* (the backward pass) is ~12x slower than the
     equivalent dot at these tiny channel counts, so the matmul form makes
-    group-wise training tractable on the host; on TPU the fused Pallas kernel
-    (repro.kernels.enhancer_fused) replaces the inference path anyway.
+    group-wise training tractable on the host (a choice tuned on the CPU).
+    Training and the CPU's inference use it; inference on the TPU runs the
+    grouped Pallas kernel (repro.kernels.enhancer_fused) instead.
     x: [B, H, W, Cin]; w: [3, 3, Cin, Cout].
     """
     p = _shifts3x3(x)  # [B,H,W,9,Cin]
@@ -104,12 +103,8 @@ def apply(
     else:
         mean, var = state["mean"], state["var"]
         new_state = state
-    h = (h - mean) * lax.rsqrt(var + _BN_EPS) * params["gamma"] + params["beta"]
+    h = (h - mean) * lax.rsqrt(var + BN_EPS) * params["gamma"] + params["beta"]
     h = jax.nn.relu(h)
     out = _conv(h, params["w2"], params["b2"])
     return out[..., 0], new_state
 
-
-# Fused Pallas forward (inference hot path) is selected via use_pallas=True in
-# the pipeline; see repro.kernels.enhancer_fused / repro.kernels.ops.
-apply_inference = partial(apply, train=False)

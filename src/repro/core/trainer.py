@@ -21,6 +21,7 @@ import numpy as np
 
 from repro import obs
 from repro.core import enhancer, grouping
+from repro.kernels import ops
 from repro.optim import AdamWConfig
 from repro.optim import adamw
 from repro.optim.schedule import step_decay
@@ -383,39 +384,27 @@ def _bn_calibrate(params, xs, ids, edges, *, n_groups):
     return {"mean": mean, "var": var / cnt}
 
 
-@partial(jax.jit, static_argnames=("n_groups", "residual_learning"))
-def _enhance_slices(params, bn_state, xs, edges, rscale, *, n_groups, residual_learning=True):
-    ids = grouping.assign_groups(xs, edges)
-    xn, masks = _group_inputs(xs, ids, edges, n_groups)
-
-    def one(p, st, xg):
-        pred, _ = enhancer.apply(p, st, xg, train=False)
-        return pred
-
-    preds = jax.vmap(one)(params, bn_state, xn)  # [G,B,H,W]
-    if residual_learning:
-        rhat = (preds * rscale[:, None, None, None] * masks).sum(axis=0)
-        return xs + rhat
-    lo, scale = grouping.group_normalizers(edges)
-    xhat = (preds * scale[:, None, None, None] + lo[:, None, None, None]) * masks
-    return xhat.sum(axis=0)
+@partial(jax.jit, static_argnames=("n_groups", "residual_learning", "use_clamp"))
+def _enhance_slices(params, bn_state, xs, edges, rscale, clamp_eb, *, n_groups,
+                    residual_learning, use_clamp):
+    return ops.enhancer_fused_op(xs, params, bn_state, edges, rscale, clamp_eb,
+                                 n_groups=n_groups,
+                                 residual_learning=residual_learning,
+                                 use_clamp=use_clamp)
 
 
 def _enhance_one_tile(params, bn_state, t, edges, rscale, clamp_eb, *,
                       n_groups, residual_learning, slice_axis, batch, use_clamp):
     """One tile's enhancement as a pure traced program — the same op sequence
     :func:`enhance` runs (moveaxis, slice-batched ``_enhance_slices``,
-    optional clamp, concat, moveaxis back), so the two paths agree bit-for-
-    bit on every backend."""
+    concat, moveaxis back), so the two paths agree bit-for-bit on every
+    backend."""
     xs = jnp.moveaxis(t, slice_axis, 0)
-    outs = []
-    for i in range(0, xs.shape[0], batch):
-        xb = xs[i : i + batch]
-        out = _enhance_slices(params, bn_state, xb, edges, rscale,
-                              n_groups=n_groups, residual_learning=residual_learning)
-        if use_clamp:
-            out = jnp.clip(out, xb - clamp_eb, xb + clamp_eb)
-        outs.append(out)
+    outs = [_enhance_slices(params, bn_state, xs[i : i + batch], edges, rscale,
+                            clamp_eb, n_groups=n_groups,
+                            residual_learning=residual_learning,
+                            use_clamp=use_clamp)
+            for i in range(0, xs.shape[0], batch)]
     return jnp.moveaxis(jnp.concatenate(outs, axis=0), 0, slice_axis)
 
 
@@ -429,6 +418,17 @@ def _enhance_tiles_mapped(params, bn_state, tiles, edges, rscale, clamp_eb, *,
             residual_learning=residual_learning, slice_axis=slice_axis,
             batch=batch, use_clamp=use_clamp),
         tiles)
+
+
+def _count_path(n: int, plane: tuple, batch: int, nbytes: int) -> None:
+    """Count an enhancement of n slices of ``plane`` shape, run in slice
+    batches of ``batch``, under the path it takes: ``gwlz.enhance.kernel``
+    when every batch runs the kernel, else ``gwlz.enhance.jnp``; the bytes
+    are the input's."""
+    paths = {ops.enhancer_path((min(batch, n - i),) + plane)
+             for i in range(0, n, batch)}
+    obs.count("gwlz.enhance.kernel" if paths == {"kernel"} else "gwlz.enhance.jnp",
+              nbytes)
 
 
 def enhance_tiles(
@@ -448,6 +448,9 @@ def enhance_tiles(
     per-tile Python loop (~n_tiles jit dispatches on the decode hot path;
     speedup measured by ``throughput/tiled/enhance_batched``)."""
     cfg = model.cfg
+    tile = tuple(tiles.shape[1:])
+    _count_path(tile[cfg.slice_axis],
+                tile[:cfg.slice_axis] + tile[cfg.slice_axis + 1:], batch, tiles.nbytes)
     clamp = jnp.float32(0.0 if clamp_eb is None else clamp_eb)
     return _enhance_tiles_mapped(
         model.params, model.bn_state, tiles, model.edges, model.rscale, clamp,
@@ -482,15 +485,13 @@ def enhance(
     """
     cfg = model.cfg
     xs = _as_slices(jnp.asarray(xprime, jnp.float32), cfg.slice_axis)
-    outs = []
-    for i in range(0, xs.shape[0], batch):
-        xb = xs[i : i + batch]
-        out = _enhance_slices(
-            model.params, model.bn_state, xb, model.edges, model.rscale,
-            n_groups=cfg.n_groups, residual_learning=cfg.residual_learning,
-        )
-        if clamp_eb is not None:
-            out = jnp.clip(out, xb - clamp_eb, xb + clamp_eb)
-        outs.append(out)
+    _count_path(xs.shape[0], xs.shape[1:], batch, xs.nbytes)
+    clamp = jnp.float32(0.0 if clamp_eb is None else clamp_eb)
+    outs = [_enhance_slices(
+                model.params, model.bn_state, xs[i : i + batch], model.edges,
+                model.rscale, clamp, n_groups=cfg.n_groups,
+                residual_learning=cfg.residual_learning,
+                use_clamp=clamp_eb is not None)
+            for i in range(0, xs.shape[0], batch)]
     enhanced = jnp.concatenate(outs, axis=0)
     return jnp.moveaxis(enhanced, 0, cfg.slice_axis)

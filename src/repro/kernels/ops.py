@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.enhancer_fused import enhancer_fused
+from repro.kernels.enhancer_fused import (
+    enhancer_grouped, fits as enhancer_fits, param_table as enhancer_param_table)
 from repro.kernels.group_hist import group_hist, symbol_hist
 from repro.kernels.huffman_decode import huffman_decode_probe
 from repro.kernels.huffman_encode import huffman_encode_pack
@@ -62,17 +63,31 @@ def lorenzo_decode_tiles_op(codes, eb):
     return _lorenzo_decode_tiles(codes, float(eb))
 
 
-def enhancer_fused_op(x, params, bn_state, *, use_pallas: bool | None = None,
-                      interpret: bool | None = None):
-    """params/bn_state: single-group enhancer pytrees (no G axis)."""
-    args = (
-        x, params["w1"], params["b1"], params["gamma"], params["beta"],
-        bn_state["mean"], bn_state["var"], params["w2"], params["b2"],
-    )
+def enhancer_path(slice_shape) -> str:
+    """``"kernel"`` where the grouped enhancer kernel runs slices of this
+    [B, H, W] shape (on the TPU, in blocks it fits), else ``"jnp"``."""
+    return "kernel" if _on_tpu() and enhancer_fits(slice_shape) else "jnp"
+
+
+def enhancer_fused_op(xs, params, bn_state, edges, rscale, clamp_eb, *,
+                      n_groups: int, residual_learning: bool, use_clamp: bool,
+                      use_pallas: bool | None = None, interpret: bool | None = None):
+    """Group-wise enhancement of slices xs [B, H, W] by the G enhancers
+    (params/bn_state leaves with a leading G axis).  The kernel takes the
+    shapes it fits (``enhancer_path``); other shapes run the reference."""
     use = _on_tpu() if use_pallas is None else use_pallas
-    if use:
-        return enhancer_fused(*args, interpret=not _on_tpu() if interpret is None else interpret)
-    return ref.enhancer_fused_ref(*args)
+    if use and enhancer_fits(xs.shape):
+        table = enhancer_param_table(params, bn_state, edges, rscale,
+                                     residual_learning=residual_learning)
+        return enhancer_grouped(
+            xs, table, edges, clamp_eb, n_groups=n_groups,
+            channels=params["b1"].shape[-1], residual_learning=residual_learning,
+            use_clamp=use_clamp,
+            interpret=not _on_tpu() if interpret is None else interpret)
+    return ref.enhancer_grouped_ref(params, bn_state, xs, edges, rscale, clamp_eb,
+                                    n_groups=n_groups,
+                                    residual_learning=residual_learning,
+                                    use_clamp=use_clamp)
 
 
 def symbol_hist_op(symbols, *, n_bins: int, use_pallas: bool | None = None,

@@ -24,17 +24,29 @@ def lorenzo_quant_tiles_ref(x: jax.Array, eb: float) -> jax.Array:
     return jax.vmap(lambda t: lorenzo_quant_ref(t, eb))(x)
 
 
-def enhancer_fused_ref(x: jax.Array, w1, b1, gamma, beta, mean, var, w2, b2) -> jax.Array:
-    """Conv3x3(1->C) + BN(inference) + ReLU + Conv3x3(C->1), zero-pad SAME.
+def enhancer_grouped_ref(params, bn_state, xs, edges, rscale, clamp_eb, *,
+                         n_groups: int, residual_learning: bool,
+                         use_clamp: bool) -> jax.Array:
+    """Group-wise enhancement of slices xs [B, H, W] by G enhancers (pytrees
+    with a leading G axis): every group's forward over every pixel, each
+    masked to its own group's pixels, then X' + R_hat (or the direct form)
+    and the optional clamp to [X' - eb, X' + eb]."""
+    from repro.core import enhancer, grouping
+    from repro.core.trainer import _group_inputs
 
-    x: [B, H, W]; returns [B, H, W]."""
-    from repro.core.enhancer import _conv
-
-    h = _conv(x[..., None], w1, b1)
-    h = (h - mean) * jax.lax.rsqrt(var + 1e-5) * gamma + beta
-    h = jax.nn.relu(h)
-    out = _conv(h, w2, b2)
-    return out[..., 0]
+    ids = grouping.assign_groups(xs, edges)
+    xn, masks = _group_inputs(xs, ids, edges, n_groups)
+    preds = jax.vmap(lambda p, st, xg: enhancer.apply(p, st, xg, train=False)[0])(
+        params, bn_state, xn)  # [G, B, H, W]
+    if residual_learning:
+        out = xs + (preds * rscale[:, None, None, None] * masks).sum(axis=0)
+    else:
+        lo, scale = grouping.group_normalizers(edges)
+        out = ((preds * scale[:, None, None, None] + lo[:, None, None, None])
+               * masks).sum(axis=0)
+    if use_clamp:
+        out = jnp.clip(out, xs - clamp_eb, xs + clamp_eb)
+    return out
 
 
 def symbol_hist_ref(s: jax.Array, n_bins: int) -> jax.Array:

@@ -123,3 +123,23 @@ def test_enhancer_pass_fits_hbm(one_chip, program, tiles):
     m = _compile(fn, *args).memory_analysis()
     used = m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes
     assert used < HBM_BYTES, f"{program} at {tiles} tiles needs {used / 2**30:.2f} GiB"
+
+
+def test_enhance_tiles_mapped_runs_the_kernel(one_chip, monkeypatch):
+    """Full decode's enhancer program at the cells' shape: one bucket of 32
+    64^3 tiles (the bucket cap), G=20, C=9, clamped, on the chip's path (the
+    grouped Pallas kernel, which the program picks on a TPU backend)."""
+    from repro.core import trainer
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    G = 20
+    params, bn = _enhancer_specs(one_chip, G=G)
+    fn = partial(trainer._enhance_tiles_mapped, n_groups=G, residual_learning=True,
+                 slice_axis=0, batch=64, use_clamp=True)
+    c = _compile(fn, params, bn, _spec(one_chip, (32, 64, 64, 64)),
+                 _spec(one_chip, (G + 1,)), _spec(one_chip, (G,)), _spec(one_chip, ()))
+    assert _has_kernel(c)
+    m = c.memory_analysis()
+    used = m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes
+    assert used < HBM_BYTES, f"_enhance_tiles_mapped needs {used / 2**30:.2f} GiB"

@@ -85,29 +85,111 @@ def test_lorenzo_roundtrip_through_decoder():
     assert float(jnp.max(jnp.abs(x2 - x))) <= eb * (1 + 1e-6)
 
 
-@pytest.mark.parametrize("shape", [(1, 16, 32), (3, 32, 64), (2, 48, 48)])
+def _enhancers(n_groups, channels=9, seed=0):
+    """G perturbed enhancers (every weight and bias nonzero) with BN stats."""
+    rng = np.random.default_rng(seed)
+    p = jax.vmap(lambda k: E.init_params(k, channels))(
+        jax.random.split(jax.random.PRNGKey(seed), n_groups))
+    p = jax.tree.map(
+        lambda a: a + jnp.asarray(rng.normal(size=a.shape) * 0.1, jnp.float32), p)
+    s = {"mean": jnp.asarray(rng.normal(size=(n_groups, channels)), jnp.float32),
+         "var": jnp.asarray(rng.uniform(0.5, 2, size=(n_groups, channels)),
+                            jnp.float32)}
+    return p, s
+
+
+def _grouped_case(shape, n_groups, seed=0):
+    """Smooth slices, edges whose last group is empty (its low edge lies
+    above the data) when G > 1, and group 1 gated (rscale 0) when G > 2."""
+    from repro.core import grouping
+
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(np.cumsum(rng.normal(size=shape), axis=1).astype(np.float32))
+    if n_groups == 1:
+        edges = jnp.stack([jnp.min(x), jnp.max(x)])
+    else:
+        q = jnp.quantile(x, jnp.linspace(0.0, 1.0, n_groups))
+        edges = jnp.concatenate([q[:-1], q[-1:] + 1.0, q[-1:] + 2.0])
+    rscale = jnp.asarray(rng.uniform(0.1, 1.0, size=n_groups), jnp.float32)
+    if n_groups > 2:
+        rscale = rscale.at[1].set(0.0)
+    ids = np.asarray(grouping.assign_groups(x, edges))
+    if n_groups > 1:
+        assert not (ids == n_groups - 1).any()  # the empty group
+    return x, edges, rscale
+
+
+def _enhance_both(x, edges, rscale, n_groups, *, residual, clamp, seed=0):
+    p, s = _enhancers(n_groups, seed=seed)
+    kw = dict(n_groups=n_groups, residual_learning=residual, use_clamp=clamp)
+    eb = jnp.float32(0.05)
+    got = ops.enhancer_fused_op(x, p, s, edges, rscale, eb, use_pallas=True,
+                                interpret=True, **kw)
+    want = ref.enhancer_grouped_ref(p, s, x, edges, rscale, eb, **kw)
+    return np.asarray(got), np.asarray(want)
+
+
+def _assert_f32_close(got, want):
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * max(1.0, float(np.abs(want).max())))
+
+
+@pytest.mark.parametrize("shape", [(4, 64, 64), (4, 16, 32), (2, 24, 64)])
 def test_enhancer_fused_matches_ref(shape):
-    rng = np.random.default_rng(shape[1])
-    key = jax.random.PRNGKey(0)
-    p = E.init_params(key)
-    s = {"mean": jnp.asarray(rng.normal(size=9), jnp.float32),
-         "var": jnp.asarray(rng.uniform(0.5, 2, size=9), jnp.float32)}
-    x = jnp.asarray(rng.normal(size=shape).astype(np.float32))
-    a = ops.enhancer_fused_op(x, p, s, use_pallas=True, interpret=True)
-    b = ref.enhancer_fused_ref(x, p["w1"], p["b1"], p["gamma"], p["beta"],
-                               s["mean"], s["var"], p["w2"], p["b2"])
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=1e-5)
+    """The grouped kernel (interpret mode) against the G-fold jnp reference:
+    two lane-packed pairs of 64x64 slices in one block, four 16x32 slices on
+    the lanes, and 24-row slices (a block height that is not whole steps of
+    the per-pixel loop)."""
+    from repro.kernels.enhancer_fused import fits
+
+    assert fits(shape)
+    x, edges, rscale = _grouped_case(shape, 4, seed=shape[1])
+    _assert_f32_close(*_enhance_both(x, edges, rscale, 4, residual=True,
+                                     clamp=False, seed=shape[2]))
 
 
 def test_enhancer_fused_matches_training_forward():
-    """Fused kernel == the exact inference path used by the trainer."""
-    key = jax.random.PRNGKey(3)
-    p = E.init_params(key)
-    s = E.init_state()
-    x = jax.random.normal(jax.random.PRNGKey(4), (2, 32, 32))
-    want, _ = E.apply(p, s, x, train=False)
-    got = ops.enhancer_fused_op(x, p, s, use_pallas=True, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-5)
+    """One group over every pixel: the kernel's residual is the training
+    forward (``enhancer.apply``) of the normalized slices, times rscale."""
+    x, edges, _ = _grouped_case((4, 32, 32), 1, seed=3)
+    p, s = _enhancers(1, seed=3)
+    rscale = jnp.asarray([0.5], jnp.float32)
+    got = ops.enhancer_fused_op(x, p, s, edges, rscale, jnp.float32(0.0),
+                                n_groups=1, residual_learning=True, use_clamp=False,
+                                use_pallas=True, interpret=True)
+    xn = (x - edges[0]) / (edges[1] - edges[0])
+    pred, _ = E.apply(jax.tree.map(lambda a: a[0], p), jax.tree.map(lambda a: a[0], s),
+                      xn, train=False)
+    np.testing.assert_allclose(np.asarray(got - x), np.asarray(pred * 0.5),
+                               atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("residual,clamp", [(True, False), (True, True),
+                                            (False, False), (False, True)],
+                         ids=["residual", "residual-clamp", "direct", "direct-clamp"])
+@pytest.mark.parametrize("n_groups", [1, 4, 20])
+def test_enhancer_grouped_matches_ref(n_groups, residual, clamp):
+    """G = 1, 4, 20 groups of the published width (C = 9) on 64x64 slices,
+    with an empty and a gated group where G > 2, clamp on and off, and the
+    direct (non-residual) form."""
+    x, edges, rscale = _grouped_case((2, 64, 64), n_groups, seed=n_groups)
+    got, want = _enhance_both(x, edges, rscale, n_groups, residual=residual,
+                              clamp=clamp, seed=n_groups)
+    _assert_f32_close(got, want)
+    if clamp:  # inside [x - eb, x + eb] as float32 computes the bounds
+        xs, eb = np.asarray(x), np.float32(0.05)
+        assert ((got >= xs - eb) & (got <= xs + eb)).all()
+
+
+def test_enhancer_op_takes_the_reference_where_the_kernel_does_not_fit():
+    """Slices whose width does not divide the lanes run the reference, even
+    with the kernel asked for."""
+    from repro.kernels.enhancer_fused import fits
+
+    x, edges, rscale = _grouped_case((3, 8, 48), 4, seed=5)
+    assert not fits(x.shape)
+    got, want = _enhance_both(x, edges, rscale, 4, residual=True, clamp=False)
+    np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("n_groups", [2, 5, 16])

@@ -344,6 +344,36 @@ def test_batched_tile_enhancement_bitexact_vs_loop(vol):
         np.testing.assert_array_equal(np.asarray(batched), np.asarray(looped))
 
 
+def test_enhance_tiles_counts_the_path_it_takes(vol, monkeypatch):
+    """One ``enhance_tiles`` call records one count, under the path its
+    slices take, carrying the input tiles' bytes: the reference on the CPU;
+    the kernel where the backend is a TPU and the slices fit its blocks."""
+    from repro import obs
+    from repro.core import trainer
+    from repro.core.pipeline import deserialize_model
+    from repro.kernels import ops
+
+    gw = GWLZ(train_cfg=GWLZTrainConfig(n_groups=4, epochs=1, batch_size=8,
+                                        min_group_pixels=64))
+    art, _ = gw.compress_tiled(vol, (8, 16, 8), rel_eb=1e-3)
+    model = deserialize_model(art.extras["gwlz"])
+    recon_tiles, _ = tiled.decode_lanes(art, range(art.n_tiles))
+    with obs.collect() as col:
+        trainer.enhance_tiles(recon_tiles, model)
+    st = col.stages()
+    assert st["gwlz.enhance.jnp"][0] == 1
+    assert st["gwlz.enhance.jnp"][2] == recon_tiles.nbytes
+    assert "gwlz.enhance.kernel" not in st
+    # the choice is by shape: on a TPU, 8 slices of 16x16 fill whole 128-lane
+    # rows of the kernel's blocks, and 5 do not
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    with obs.collect() as col:
+        trainer._count_path(8, (16, 16), 64, 4096)
+        trainer._count_path(5, (16, 16), 64, 2560)
+    assert col.stages()["gwlz.enhance.kernel"][::2] == (1, 4096)
+    assert col.stages()["gwlz.enhance.jnp"][::2] == (1, 2560)
+
+
 def test_gwlz_tiled_enhancement_improves_or_gates(vol):
     """With a real training budget the enhancer must help (or gate itself off
     to identity) — never hurt the tiled reconstruction."""
