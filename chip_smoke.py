@@ -28,8 +28,10 @@ group-wise enhancer at its published width (G=20 groups, C=9 channels,
    the device decode probe's symbols == the host walk's.
 
 ``--chips 4`` runs only the phase that needs the mesh: the same ingest over a
-4-device tile mesh and pinned to one of those devices, whose containers must
-be byte-identical and read the same ROI.
+4-device tile mesh (tile batches and the enhancer's groups split over the
+devices) and pinned to one of those devices, whose containers must be
+byte-identical and read the same ROI.  Each ingest's line gives its
+enhancer training seconds (``gwlz.train``).
 
 Earlier lines report each phase; the last line of stdout is one JSON object,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
@@ -99,11 +101,12 @@ def ingest(x, path, *, enhance: bool):
         x, path, eb=REL_EB, tile=TILE, predictor="lorenzo",
         enhance=GWLZTrainConfig(epochs=EPOCHS) if enhance else False)
     check(rep.enhanced == enhance, f"enhanced={rep.enhanced}, wanted {enhance}")
+    train_s = rep.stages.get("gwlz.train", (0, 0.0, 0))[1]
     print(f"  {Path(path).name}: {rep.nbytes} bytes, {rep.n_batches} batches "
           f"of {rep.batch_tiles} tiles, reservoir {rep.reservoir_tiles} tiles, "
           f"programs_compiled {rep.programs_compiled}, host_stage "
-          f"{rep.host_stage_s:.3f} s, entropy_device {rep.entropy_device}",
-          flush=True)
+          f"{rep.host_stage_s:.3f} s, train {train_s:.3f} s, entropy_device "
+          f"{rep.entropy_device}", flush=True)
     return rep
 
 

@@ -1,9 +1,16 @@
 """Group-wise residual training (paper §3.2-3.3).
 
-All G enhancers are trained *simultaneously* as one SPMD program: the group
-axis is a leading batch axis of the parameter pytree (``vmap`` over models).
-On a production mesh the group axis maps to ``model`` and the slice batch to
-``data`` (see repro.launch.gwlz_dist); on one host it is a plain vmap.
+All G enhancers are trained *simultaneously*: the group axis is a leading
+batch axis of the parameter pytree (``vmap`` over models).
+The G models are independent (own parameters, BN state, Adam state and
+masked loss), so the normal path splits the group axis into blocks dealt to
+the tile devices (:func:`repro.launch.sharding.tile_devices`), like experts
+in expert parallelism: on a multi-device host each device holds its blocks'
+state, builds the masks of its own groups only, and no gradient crosses
+devices; the slices are copied to every device.  The blocks and their
+programs are the same on any device count (:data:`GROUP_BLOCKS`).
+(``repro.launch.gwlz_dist`` is a dry-run sketch of the same mapping with the
+slice batch also sharded.)
 
 Faithful knobs (paper §4.1): C=9 channels / 2 convs (~200 params per model),
 batch of 10 slices, 300 epochs, Adam lr 1e-3 with a step decay every 30
@@ -12,8 +19,9 @@ Fig. 5 (predict the original data directly instead of the residual).
 """
 from __future__ import annotations
 
+from contextlib import nullcontext
 from dataclasses import dataclass, field, replace
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +30,7 @@ import numpy as np
 from repro import obs
 from repro.core import enhancer, grouping
 from repro.kernels import ops
+from repro.launch import sharding
 from repro.optim import AdamWConfig
 from repro.optim import adamw
 from repro.optim.schedule import step_decay
@@ -74,12 +83,41 @@ def _per_group_scale(r: jax.Array, ids: jax.Array, n_groups: int) -> jax.Array:
     return jnp.maximum(s, 1e-12)
 
 
+def _block_inputs(xb, idsb, groups):
+    """Normalized, masked inputs of a block of groups: [g, B, H, W] (+ masks).
+
+    ``groups`` is the block's slice of a :func:`_group_table`; masks are
+    built for the block's own groups only."""
+    at = lambda v: v[:, None, None, None]  # noqa: E731
+    masks = (idsb[None] == at(groups["id"])).astype(xb.dtype)  # [g,B,H,W]
+    xn = (xb[None] - at(groups["lo"])) / at(groups["scale"])
+    return xn * masks, masks
+
+
 def _group_inputs(xb, idsb, edges, n_groups):
     """Normalized, masked inputs for every group: [G, B, H, W] (+ masks)."""
-    lo, scale = grouping.group_normalizers(edges)
-    masks = jax.nn.one_hot(idsb, n_groups, axis=0, dtype=xb.dtype)  # [G,B,H,W]
-    xn = (xb[None] - lo[:, None, None, None]) / scale[:, None, None, None]
-    return xn * masks, masks
+    return _block_inputs(xb, idsb, _group_table(edges, jnp.zeros(n_groups), n_groups))
+
+
+def _pad_groups(tree, n: int):
+    """Pad every leaf's leading group axis to ``n`` with copies of group 0."""
+    def pad(a):
+        k = n - a.shape[0]
+        return a if k == 0 else jnp.concatenate([a, jnp.repeat(a[:1], k, axis=0)])
+    return jax.tree.map(pad, tree)
+
+
+def _group_table(edges, rscale, n: int) -> dict:
+    """The per-group operands of the training programs, over ``n >= G``
+    groups: each group's id, input normalizers and residual scale.
+
+    The programs take the ids as an operand, never as constants, so every
+    block of groups runs one program, whichever device holds it.  Groups
+    past G are padding: rscale 0 (inactive, so no gradient) and an id no
+    pixel has, so they never reach the model."""
+    lo, scale = _pad_groups(grouping.group_normalizers(edges), n)
+    return {"id": jnp.arange(n, dtype=jnp.int32), "lo": lo, "scale": scale,
+            "rscale": jnp.pad(rscale, (0, n - rscale.shape[0]))}
 
 
 def _loss_one_group(params, state, xg, maskg, target):
@@ -89,7 +127,7 @@ def _loss_one_group(params, state, xg, maskg, target):
     return loss, new_state
 
 
-@partial(jax.jit, static_argnames=("n_groups", "residual_learning", "adam_cfg"))
+@partial(jax.jit, static_argnames=("residual_learning", "adam_cfg"))
 def train_step(
     params,
     bn_state,
@@ -97,24 +135,24 @@ def train_step(
     xb,
     rb,
     idsb,
-    edges,
-    rscale,
+    groups,
     lr,
     *,
-    n_groups: int,
     residual_learning: bool,
     adam_cfg: AdamWConfig,
 ):
-    """One Adam step for all G models at once.  Returns per-group losses."""
-    xn, masks = _group_inputs(xb, idsb, edges, n_groups)
+    """One Adam step for a block of group models at once.  ``groups`` is the
+    block's :func:`_group_table`.  Returns per-group losses."""
+    xn, masks = _block_inputs(xb, idsb, groups)
+    rscale = groups["rscale"]
     if residual_learning:
         safe = jnp.where(rscale > 0, rscale, 1.0)
         target = rb[None] / safe[:, None, None, None] * masks
     else:
         # Regular baseline: predict the normalized original directly.
-        lo, scale = grouping.group_normalizers(edges)
         orig = xb[None] + rb[None]  # X = X' + R
-        target = (orig - lo[:, None, None, None]) / scale[:, None, None, None] * masks
+        target = ((orig - groups["lo"][:, None, None, None])
+                  / groups["scale"][:, None, None, None] * masks)
 
     active = (rscale > 0.0).astype(jnp.float32)
 
@@ -125,6 +163,42 @@ def train_step(
     grads, (losses, new_bn) = jax.grad(lossfn, has_aux=True)(params)
     new_params, new_opt = adamw.update(params, opt_state, grads, lr, adam_cfg)
     return new_params, new_bn, new_opt, losses
+
+
+@jax.jit
+def _take(arrays, idx):
+    """One device's batch: the slices ``idx`` of each of its arrays, in one
+    dispatch."""
+    return tuple(a[idx] for a in arrays)
+
+
+def _tree_bytes(tree) -> int:
+    return sum(int(a.nbytes) for a in jax.tree.leaves(tree))
+
+
+# The group axis always trains as this many equal blocks (G padded to a
+# multiple), each block by the same programs: one block per chip of a
+# four-chip host, and all four in turn on one device.  A block's rounding
+# then does not depend on how many devices share the blocks: batching a
+# different number of groups into one program changes how the TPU compiler
+# folds the group axis into the weight-gradient convolutions' windows.
+GROUP_BLOCKS = 4
+
+
+def _split_groups(tree, placement) -> list:
+    """Block ``b`` of every leaf's group axis, placed on ``placement[b]``
+    (leaves with no group axis, like Adam's step count, go whole)."""
+    n = len(placement)
+    return [jax.device_put(jax.tree.map(
+                lambda a: a[b * (a.shape[0] // n):(b + 1) * (a.shape[0] // n)]
+                if a.ndim else a, tree), d)
+            for b, d in enumerate(placement)]
+
+
+def _join_groups(blocks, n_groups: int):
+    """The blocks' group axes concatenated on the host, cut to ``n_groups``."""
+    return jax.tree.map(lambda *a: jnp.asarray(np.concatenate(a)[:n_groups]),
+                        *blocks)
 
 
 def train_enhancers(
@@ -138,8 +212,17 @@ def train_enhancers(
 
     Returns (model, history) where history["loss"][epoch, group] traces the
     per-group training loss (Fig. 5 reproduction).
+
+    The group axis trains as :data:`GROUP_BLOCKS` blocks (G padded with
+    inactive groups to a multiple), dealt round-robin to the tile devices
+    (:func:`repro.launch.sharding.tile_devices`), each of which gets a copy
+    of the slices.  Every block runs the same programs on any device, so
+    the model does not depend on the device count.
     """
     G = cfg.n_groups
+    devices = sharding.tile_devices()
+    placement = [devices[b % len(devices)] for b in range(GROUP_BLOCKS)]
+    n_pad = -(-G // GROUP_BLOCKS) * GROUP_BLOCKS
     with obs.span("gwlz.train.groups"):
         xs = _as_slices(jnp.asarray(xprime, jnp.float32), cfg.slice_axis)
         rs = _as_slices(jnp.asarray(residual, jnp.float32), cfg.slice_axis)
@@ -156,8 +239,22 @@ def train_enhancers(
         params = jax.vmap(lambda k: enhancer.init_params(k, cfg.channels))(pkeys)
         bn_state = jax.vmap(lambda _: enhancer.init_state(cfg.channels))(
             jnp.arange(G))
+        params, bn_state = _pad_groups((params, bn_state), n_pad)
+        groups = _group_table(edges, rscale, n_pad)
         adam_cfg = AdamWConfig()
         opt_state = adamw.init(params, adam_cfg)
+    # each block: [params, bn_state, opt_state, groups]; each device: a copy
+    # of (xs, rs, ids)
+    state = [params, bn_state, opt_state, groups]
+    used = list(dict.fromkeys(placement))
+    on_mesh = len(used) > 1
+    with (obs.span("gwlz.train.shard", _tree_bytes(state)
+                   + len(used) * _tree_bytes((xs, rs, ids))) if on_mesh else nullcontext()):
+        blocks = _split_groups(state, placement)
+        data = {d: jax.device_put((xs, rs, ids), d) for d in used}
+    if on_mesh:  # the group state of the device holding the most blocks
+        obs.count("gwlz.train.group_mesh",
+                  placement.count(used[0]) * _tree_bytes(blocks[0][:3]))
 
     bs = min(cfg.batch_size, n_slices)
     steps_per_epoch = max(n_slices // bs, 1)
@@ -172,29 +269,41 @@ def train_enhancers(
         for s in range(steps_per_epoch):
             with obs.span("gwlz.train.step"):
                 idx = order[s * bs : (s + 1) * bs]
-                xb, rb, idsb = xs[idx], rs[idx], ids[idx]
                 lr = sched(gstep)
-                params, bn_state, opt_state, losses = train_step(
-                    params, bn_state, opt_state, xb, rb, idsb, edges, rscale, lr,
-                    n_groups=G, residual_learning=cfg.residual_learning,
-                    adam_cfg=adam_cfg,
-                )
-                ep_loss += np.asarray(losses, np.float64)
+                batch = {d: _take(arrays, idx) for d, arrays in data.items()}
+                losses = []
+                for blk, d in zip(blocks, placement):
+                    p, bn, opt, grp = blk
+                    p, bn, opt, loss = train_step(
+                        p, bn, opt, *batch[d], grp, lr,
+                        residual_learning=cfg.residual_learning, adam_cfg=adam_cfg,
+                    )
+                    blk[:3] = p, bn, opt
+                    losses.append(loss)
+                ep_loss += np.concatenate(jax.device_get(losses))[:G]
             gstep += 1
         history["loss"][epoch] = ep_loss / steps_per_epoch
         history["lr"][epoch] = float(sched(gstep - 1))
         if callback is not None:
             callback(epoch, history["loss"][epoch])
+    params = [blk[0] for blk in blocks]
     # Replace running BN stats with exact full-volume statistics (the data we
     # will enhance is exactly the data we trained on — see _bn_calibrate).
     with obs.span("gwlz.train.calibrate"):
-        bn_state = _bn_calibrate(params, xs, ids, edges, n_groups=G)
+        bn_state = [_bn_calibrate(p, data[d][0], data[d][2], blk[3])
+                    for p, blk, d in zip(params, blocks, placement)]
+    gate = None
     if cfg.gate_groups and cfg.residual_learning:
         with obs.span("gwlz.train.gate"):
-            gate = _gate_groups(params, bn_state, xs, rs, ids, edges, rscale,
-                                n_groups=G)
-            rscale = rscale * gate
-            history["gate"] = np.asarray(gate)
+            gate = [_gate_groups(p, bn, *data[d], blk[3])
+                    for p, bn, blk, d in zip(params, bn_state, blocks, placement)]
+    with (obs.span("gwlz.train.gather", _tree_bytes((params, bn_state, gate)))
+          if on_mesh else nullcontext()):
+        params, bn_state, gate = (part and _join_groups(part, G)
+                                  for part in (params, bn_state, gate))
+    if gate is not None:
+        rscale = rscale * gate
+        history["gate"] = np.asarray(gate)
     model = GWLZModel(params=params, bn_state=bn_state, edges=edges, rscale=rscale, cfg=cfg)
     return model, history
 
@@ -309,10 +418,14 @@ def train_enhancers_streamed(
 _PASS_PIXELS = 1 << 16
 
 
+# Group id of the pad slices of a scan: no group's, so their masks are zero
+_NO_GROUP = -1
+
+
 def _slice_batches(*arrays_and_fills):
     """Split [N, H, W] slice stacks into [nb, B, H, W] scan batches, padding
-    N up to a multiple of B with the given fill (ids pad with ``n_groups``,
-    whose one-hot mask is all zero, so pad slices add nothing to any sum)."""
+    N up to a multiple of B with the given fill (ids pad with ``_NO_GROUP``,
+    whose mask is all zero, so pad slices add nothing to any sum)."""
     n, h, w = arrays_and_fills[0][0].shape
     b = max(1, min(n, _PASS_PIXELS // (h * w)))
     pad = (-n) % b
@@ -324,44 +437,48 @@ def _slice_batches(*arrays_and_fills):
     return out
 
 
-@partial(jax.jit, static_argnames=("n_groups",))
-def _gate_groups(params, bn_state, xs, rs, ids, edges, rscale, *, n_groups):
+@jax.jit
+def _gate_groups(params, bn_state, xs, rs, ids, groups):
     """Per-group acceptance test on the training volume: keep a group's
-    enhancer only if it reduces that group's residual MSE."""
+    enhancer only if it reduces that group's residual MSE.  ``groups`` as
+    in :func:`train_step`."""
 
     def one(p, st, xg):
         pred, _ = enhancer.apply(p, st, xg, train=False)
         return pred
 
+    rscale = groups["rscale"][:, None, None, None]
+
     def step(acc, batch):
         xb, rb, ib = batch
-        xn, masks = _group_inputs(xb, ib, edges, n_groups)
-        preds = jax.vmap(one)(params, bn_state, xn) * rscale[:, None, None, None]
+        xn, masks = _block_inputs(xb, ib, groups)
+        preds = jax.vmap(one)(params, bn_state, xn) * rscale
         err_with = (((rb[None] - preds) * masks) ** 2).sum(axis=(1, 2, 3))
         err_without = ((rb[None] * masks) ** 2).sum(axis=(1, 2, 3))
         return (acc[0] + err_with, acc[1] + err_without), None
 
-    zero = jnp.zeros((n_groups,), jnp.float32)
-    batches = _slice_batches((xs, 0.0), (rs, 0.0), (ids, n_groups))
+    zero = jnp.zeros(groups["rscale"].shape, jnp.float32)
+    batches = _slice_batches((xs, 0.0), (rs, 0.0), (ids, _NO_GROUP))
     (err_with, err_without), _ = jax.lax.scan(step, (zero, zero), tuple(batches))
     return (err_with < err_without).astype(jnp.float32)
 
 
-@partial(jax.jit, static_argnames=("n_groups",))
-def _bn_calibrate(params, xs, ids, edges, *, n_groups):
+@jax.jit
+def _bn_calibrate(params, xs, ids, groups):
     """Exact masked BN statistics of the *final* model over the full volume.
 
     Per-batch BN statistics drift from the running average enough to cost
     ~1 dB at inference; since compression trains on exactly the data it will
     enhance, we can use the exact statistics (one extra forward pass).  Two
     passes over slice batches: per-group masked sums give the mean, then the
-    masked squared deviations from it give the variance."""
-    batches = tuple(_slice_batches((xs, 0.0), (ids, n_groups)))
+    masked squared deviations from it give the variance.  ``groups`` as in
+    :func:`train_step`."""
+    batches = tuple(_slice_batches((xs, 0.0), (ids, _NO_GROUP)))
 
     def conv1(xb, ib):
-        xn, masks = _group_inputs(xb, ib, edges, n_groups)
+        xn, masks = _block_inputs(xb, ib, groups)
         h = jax.vmap(lambda p, xg: enhancer._conv(xg[..., None], p["w1"], p["b1"]))(
-            params, xn)  # [G, B, H, W, C]
+            params, xn)  # [g, B, H, W, C]
         return h, masks[..., None]
 
     def sums(acc, batch):
@@ -369,10 +486,9 @@ def _bn_calibrate(params, xs, ids, edges, *, n_groups):
         return (acc[0] + (h * m).sum(axis=(1, 2, 3)),
                 acc[1] + m.sum(axis=(1, 2, 3))), None
 
-    C = params["b1"].shape[-1]
-    zero = jnp.zeros((n_groups, C), jnp.float32)
+    zero = jnp.zeros(params["b1"].shape, jnp.float32)  # [g, C]
     (total, cnt), _ = jax.lax.scan(
-        sums, (zero, jnp.zeros((n_groups, 1), jnp.float32)), batches)
+        sums, (zero, jnp.zeros(zero.shape[:1] + (1,), jnp.float32)), batches)
     cnt = jnp.maximum(cnt, 1.0)
     mean = total / cnt
 
@@ -452,10 +568,24 @@ def enhance_tiles(
     _count_path(tile[cfg.slice_axis],
                 tile[:cfg.slice_axis] + tile[cfg.slice_axis + 1:], batch, tiles.nbytes)
     clamp = jnp.float32(0.0 if clamp_eb is None else clamp_eb)
-    return _enhance_tiles_mapped(
-        model.params, model.bn_state, tiles, model.edges, model.rscale, clamp,
-        n_groups=cfg.n_groups, residual_learning=cfg.residual_learning,
-        slice_axis=cfg.slice_axis, batch=batch, use_clamp=clamp_eb is not None)
+    fn = _tile_enhancer(cfg.n_groups, cfg.residual_learning, cfg.slice_axis,
+                        batch, clamp_eb is not None)
+    return sharding.map_tiles(fn, tiles, model.params, model.bn_state,
+                              model.edges, model.rscale, clamp)
+
+
+@lru_cache(maxsize=64)
+def _tile_enhancer(n_groups, residual_learning, slice_axis, batch, use_clamp):
+    """:func:`_enhance_tiles_mapped` with its static settings bound, one
+    function object per setting, for ``sharding.map_tiles``: on a mesh each
+    device enhances its share of the tiles (the kernel cannot be
+    partitioned automatically); on one device it is a plain call."""
+    def fn(tiles, params, bn_state, edges, rscale, clamp):
+        return _enhance_tiles_mapped(
+            params, bn_state, tiles, edges, rscale, clamp, n_groups=n_groups,
+            residual_learning=residual_learning, slice_axis=slice_axis,
+            batch=batch, use_clamp=use_clamp)
+    return fn
 
 
 def enhance_tiles_looped(

@@ -9,6 +9,11 @@ the paper-derived error-bounded int8 compression (optim.grad_compress).
 
 This module also provides the dry-run cell "gwlz-nyx / vol512" — the cell
 most representative of the paper's own technique in EXPERIMENTS.md §Roofline.
+
+It is a dry-run sketch.  The normal path (``api.compress_stream`` ->
+``repro.core.trainer.train_enhancers``) splits the group axis over the tile
+devices itself, one block of groups per device, with the slices copied to
+every device rather than sharded.
 """
 from __future__ import annotations
 

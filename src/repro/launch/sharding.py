@@ -9,6 +9,7 @@ on (the 405B/671B training cells), else to None.
 from __future__ import annotations
 
 import contextlib
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -153,11 +154,12 @@ _PINNED_DEVICES: tuple | None = None
 @contextlib.contextmanager
 def pin_tile_devices(devices):
     """Within the block, :func:`tile_mesh`, :func:`device_round` and
-    :func:`map_tiles` default to ``devices`` instead of ``jax.devices()``.
+    :func:`map_tiles` default to ``devices`` instead of ``jax.devices()``,
+    and enhancer training splits its groups over ``devices``.
 
     This is how one process runs the same path on one device and on a
-    multi-device mesh: streamed ingest, decode and serving all build their
-    mesh through these helpers.  Pins do not nest."""
+    multi-device mesh: streamed ingest, training, decode and serving all take
+    their devices from :func:`tile_devices`.  Pins do not nest."""
     global _PINNED_DEVICES
     if _PINNED_DEVICES is not None:
         raise RuntimeError("tile devices are already pinned")
@@ -208,7 +210,9 @@ def map_tiles(fn, tiles, *extra, mesh=None):
     must map axis 0 elementwise (tile-independent) and preserve the batch
     axis; ``extra`` operands are replicated.  The batch is padded to a device
     multiple with repeats of tile 0 (cheap, discarded).  On a single device
-    this is a plain call — no dispatch overhead."""
+    this is a plain call — no dispatch overhead.  ``fn`` is compiled once
+    per mesh for each function object, so callers pass the same object for
+    the same program (a cached closure, never a fresh lambda per call)."""
     mesh = tile_mesh() if mesh is None else mesh
     n = int(mesh.devices.size)
     if n <= 1:
@@ -220,10 +224,18 @@ def map_tiles(fn, tiles, *extra, mesh=None):
     if pad:
         tiles = jax.tree.map(
             lambda t: jnp.concatenate([t, jnp.repeat(t[:1], pad, axis=0)]), tiles)
-    in_specs = (P("tiles"),) + (P(),) * len(extra)
-    out = jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=P("tiles"),
-                        check_vma=False)(tiles, *extra)
+    out = _shard_mapped(fn, mesh, len(extra))(tiles, *extra)
     return jax.tree.map(lambda o: o[:B], out) if pad else out
+
+
+@functools.lru_cache(maxsize=64)
+def _shard_mapped(fn, mesh, n_extra: int):
+    """``fn`` as one jitted ``shard_map`` program per (fn, mesh, operand
+    count).  A ``shard_map`` called outside ``jit`` runs op by op and traces
+    and compiles its ops anew on every call, so every tile batch compiled."""
+    in_specs = (P("tiles"),) + (P(),) * n_extra
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P("tiles"), check_vma=False))
 
 
 def cache_pspecs(cache, mesh, opts: ShardingOptions) -> object:

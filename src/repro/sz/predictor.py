@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import math
 import struct
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -343,6 +343,27 @@ class TilePredictor:
         raise NotImplementedError
 
 
+# One function object per static setting, so ``sharding.map_tiles`` compiles
+# each per-device program once per mesh.
+@lru_cache(maxsize=64)
+def _lorenzo_encoder(eb, use_pallas):
+    from repro.kernels import ops
+
+    return lambda t: ops.lorenzo_quant_tiles_op(t, eb, use_pallas=use_pallas)
+
+
+@lru_cache(maxsize=64)
+def _lorenzo_decoder(eb):
+    from repro.kernels import ops
+
+    return lambda c: ops.lorenzo_decode_tiles_op(c, eb)
+
+
+@lru_cache(maxsize=64)
+def _interp_encoder(eb, levels, order):
+    return jax.vmap(lambda t: _interp_encode_padded(t, eb, levels, order))
+
+
 @register_predictor
 class _LorenzoTiles(TilePredictor):
     """Prequant + integer Lorenzo per tile (carry cut at tile boundaries).
@@ -357,22 +378,18 @@ class _LorenzoTiles(TilePredictor):
         return 0
 
     def encode_tiles(self, tiles, eb, *, order, levels, use_pallas=None):
-        from repro.kernels import ops
         from repro.launch import sharding
 
-        codes = sharding.map_tiles(
-            lambda t: ops.lorenzo_quant_tiles_op(t, eb, use_pallas=use_pallas), tiles)
+        codes = sharding.map_tiles(_lorenzo_encoder(eb, use_pallas), tiles)
         payload = {"codes": codes}
         recon = self.decode_tiles(payload, eb, tile=tuple(tiles.shape[1:]),
                                   order=order, levels=levels)
         return payload, recon
 
     def decode_tiles(self, payload, eb, *, tile, order, levels):
-        from repro.kernels import ops
         from repro.launch import sharding
 
-        return sharding.map_tiles(
-            lambda c: ops.lorenzo_decode_tiles_op(c, eb), payload["codes"])
+        return sharding.map_tiles(_lorenzo_decoder(eb), payload["codes"])
 
     def lane_bytes(self, payload, i, backend, *, use_pallas=None):
         from repro.sz import entropy
@@ -451,8 +468,8 @@ class _InterpTiles(TilePredictor):
         pads = [(0, 0)] + [(0, p - d) for d, p in zip(tile, pshape)]
         xp = jnp.pad(tiles, pads, mode="edge")
 
-        enc = jax.vmap(lambda t: _interp_encode_padded(t, eb, levels, order))
-        codes, omask, ovals, _ = sharding.map_tiles(enc, xp)
+        codes, omask, ovals, _ = sharding.map_tiles(
+            _interp_encoder(eb, levels, order), xp)
 
         S = 1 << levels
         coarse = jnp.zeros(pshape, bool).at[

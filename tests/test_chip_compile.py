@@ -33,6 +33,20 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(scope="module")
+def four_chips():
+    """A 1-D ``tiles`` mesh over a described 2x2 v5e host."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import numpy as np
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # any failure to describe the chip means: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return jax.sharding.Mesh(np.asarray(topo.devices), ("tiles",))
+
+
 def _spec(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -100,6 +114,11 @@ def _enhancer_specs(sharding, G=20, C=9):
     return params, bn
 
 
+def _group_specs(sharding, G):
+    return {"id": _spec(sharding, (G,), jnp.int32), "lo": _spec(sharding, (G,)),
+            "scale": _spec(sharding, (G,)), "rscale": _spec(sharding, (G,))}
+
+
 @pytest.mark.parametrize("tiles", [32, 128])
 @pytest.mark.parametrize("program", ["_gate_groups", "_bn_calibrate"])
 def test_enhancer_pass_fits_hbm(one_chip, program, tiles):
@@ -113,16 +132,35 @@ def test_enhancer_pass_fits_hbm(one_chip, program, tiles):
     n = tiles * 64
     xs = _spec(one_chip, (n, 64, 64))
     ids = _spec(one_chip, (n, 64, 64), jnp.int32)
-    edges, rscale = _spec(one_chip, (G + 1,)), _spec(one_chip, (G,))
+    groups = _group_specs(one_chip, G)
     if program == "_gate_groups":
-        fn = partial(trainer._gate_groups, n_groups=G)
-        args = (params, bn, xs, xs, ids, edges, rscale)
+        fn = trainer._gate_groups
+        args = (params, bn, xs, xs, ids, groups)
     else:
-        fn = partial(trainer._bn_calibrate, n_groups=G)
-        args = (params, xs, ids, edges)
+        fn = trainer._bn_calibrate
+        args = (params, xs, ids, groups)
     m = _compile(fn, *args).memory_analysis()
     used = m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes
     assert used < HBM_BYTES, f"{program} at {tiles} tiles needs {used / 2**30:.2f} GiB"
+
+
+def test_train_step_fits_hbm(one_chip):
+    """One enhancer training step at the cells' shape (batch 10 of 64x64
+    slices, C=9) for one block of the 20 groups, the program every device
+    runs (``trainer.GROUP_BLOCKS``)."""
+    from repro.core import trainer
+    from repro.optim import AdamWConfig
+
+    groups = 20 // trainer.GROUP_BLOCKS
+    params, bn = _enhancer_specs(one_chip, G=groups)
+    opt = {"step": _spec(one_chip, (), jnp.int32), "m": params, "v": params}
+    batch = _spec(one_chip, (10, 64, 64))
+    fn = partial(trainer.train_step, residual_learning=True, adam_cfg=AdamWConfig())
+    m = _compile(fn, params, bn, opt, batch, batch,
+                 _spec(one_chip, (10, 64, 64), jnp.int32),
+                 _group_specs(one_chip, groups), _spec(one_chip, ())).memory_analysis()
+    used = m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes
+    assert used < HBM_BYTES, f"train_step at G={groups} needs {used / 2**30:.2f} GiB"
 
 
 def test_enhance_tiles_mapped_runs_the_kernel(one_chip, monkeypatch):
@@ -143,3 +181,51 @@ def test_enhance_tiles_mapped_runs_the_kernel(one_chip, monkeypatch):
     m = c.memory_analysis()
     used = m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes
     assert used < HBM_BYTES, f"_enhance_tiles_mapped needs {used / 2**30:.2f} GiB"
+
+
+def test_enhance_tiles_over_four_chips_runs_the_kernel_per_chip(four_chips, monkeypatch):
+    """Full decode's enhancer program on a four-chip host: the tile bucket
+    split over the ``tiles`` mesh, the grouped kernel on each chip.  Handed
+    mesh-sharded tiles, the jitted program alone is refused (a Mosaic kernel
+    cannot be partitioned automatically); ``sharding.map_tiles`` runs it per
+    chip."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core import trainer
+    from repro.kernels import ops
+    from repro.launch import sharding
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    G = 20
+    split, whole = NamedSharding(four_chips, P("tiles")), NamedSharding(four_chips, P())
+    params, bn = _enhancer_specs(whole, G=G)
+    fn = trainer._tile_enhancer(G, True, 0, 64, True)
+    args = (_spec(split, (32, 64, 64, 64)), params, bn, _spec(whole, (G + 1,)),
+            _spec(whole, (G,)), _spec(whole, ()))
+    c = sharding._shard_mapped(fn, four_chips, 5).lower(*args).compile()
+    assert _has_kernel(c)
+    m = c.memory_analysis()
+    used = m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes
+    assert used < HBM_BYTES, f"per-chip enhancer needs {used / 2**30:.2f} GiB"
+    with pytest.raises(Exception, match="automatically partitioned"):
+        jax.jit(fn).lower(*args).compile()
+
+
+def test_lorenzo_tiles_over_four_chips_compile_as_one_program(four_chips, monkeypatch):
+    """The streamed ingest's tile batch (8 64^3 tiles) over a four-chip
+    host: ``map_tiles`` compiles the Lorenzo kernel's per-chip program once,
+    as one jitted ``shard_map``."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels import ops
+    from repro.launch import sharding
+    from repro.sz import predictor
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    tiles = _spec(NamedSharding(four_chips, P("tiles")), (8, 64, 64, 64))
+    fn = predictor._lorenzo_encoder(0.01, None)
+    assert predictor._lorenzo_encoder(0.01, None) is fn
+    c = sharding._shard_mapped(fn, four_chips, 0).lower(tiles).compile()
+    assert _has_kernel(c)

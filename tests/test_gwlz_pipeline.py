@@ -111,7 +111,8 @@ def test_bn_calibrate_and_gate_match_whole_array_formulas():
     cnt = jnp.maximum(m.sum(axis=(1, 2, 3)), 1.0)
     mean = (h * m).sum(axis=(1, 2, 3)) / cnt
     var = ((h - mean[:, None, None, None]) ** 2 * m).sum(axis=(1, 2, 3)) / cnt
-    bn = trainer._bn_calibrate(params, xs, ids, edges, n_groups=G)
+    bn = trainer._bn_calibrate(params, xs, ids,
+                               trainer._group_table(edges, jnp.zeros(G), G))
     np.testing.assert_allclose(bn["mean"], mean, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(bn["var"], var, rtol=1e-5, atol=1e-6)
 
@@ -124,6 +125,7 @@ def test_bn_calibrate_and_gate_match_whole_array_formulas():
     rs = (preds * masks * keep).sum(axis=0) + 1e-3
     want = ((((rs[None] - preds) * masks) ** 2).sum(axis=(1, 2, 3))
             < ((rs[None] * masks) ** 2).sum(axis=(1, 2, 3)))
-    gate = trainer._gate_groups(params, bn, xs, rs, ids, edges, rscale, n_groups=G)
+    gate = trainer._gate_groups(params, bn, xs, rs, ids,
+                                trainer._group_table(edges, rscale, G))
     np.testing.assert_array_equal(np.asarray(gate), np.asarray(want, np.float32))
     assert 0 < float(gate.sum()) < G
