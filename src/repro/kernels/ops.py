@@ -145,7 +145,8 @@ def huffman_decode_op(words, starts, counts, lut, cw_map, order, len_sorted, *,
     words: [C, W] int32 per-chunk windows of big-endian u32 stream words;
     starts/counts: [C, 1] int32 first-bit offsets / symbol targets; tables
     from ``HuffmanCodec._device_tables``.  Returns alphabet ids
-    [C, chunk_size] int32 (zero-padded past each chunk's count)."""
+    [C, chunk_size] int32 (zero-padded past each chunk's count) and the
+    probe steps each block of 8 chunks ran, [ceil(C / 8)] int32."""
     use = _on_tpu() if use_pallas is None else use_pallas
     if use:
         return huffman_decode_probe(

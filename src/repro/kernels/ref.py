@@ -67,13 +67,22 @@ def huffman_encode_ref(lens: jax.Array, codes: jax.Array) -> tuple[jax.Array, ja
 
 
 def huffman_decode_ref(words, starts, counts, lut, cw_map, order, len_sorted,
-                       *, chunk_size: int, k: int, n_ids: int) -> jax.Array:
-    """Lockstep multi-symbol LUT decode probe over all chunks at once.
-    Returns alphabet ids [C, chunk_size] int32."""
+                       *, chunk_size: int, k: int, n_ids: int,
+                       block_chunks: int = 8) -> tuple[jax.Array, jax.Array]:
+    """Lockstep multi-symbol LUT decode probe, the kernel's blocks of
+    ``block_chunks`` chunks mapped over at once.  Returns alphabet ids
+    [C, chunk_size] int32 and the steps each block ran."""
     from repro.kernels.huffman_decode import _decode_block
 
-    return _decode_block(words, starts, counts, lut, cw_map, order, len_sorted,
-                         chunk_size=chunk_size, k=k, n_ids=n_ids)
+    C, bb = words.shape[0], block_chunks
+    pad = (-C) % bb  # count 0 => the lane never activates
+    blocks = [jnp.pad(a, ((0, pad), (0, 0))).reshape(-1, bb, a.shape[1])
+              for a in (words, starts, counts)]
+    out, steps = jax.vmap(
+        lambda w, s, c: _decode_block(w, s, c, lut, cw_map, order, len_sorted,
+                                      chunk_size=chunk_size, k=k, n_ids=n_ids)
+    )(*blocks)
+    return out.reshape(-1, chunk_size)[:C], steps
 
 
 def group_hist_ref(x: jax.Array, edges: jax.Array) -> tuple[jax.Array, jax.Array]:

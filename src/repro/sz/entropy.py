@@ -739,6 +739,7 @@ class HuffmanCodec:
             offsets, counts = offsets[c0:c1], counts[c0:c1]
             chunk_bits = chunk_bits[c0:c1]
             n_symbols = int(counts.sum())
+        import jax
         import jax.numpy as jnp
 
         from repro.kernels import ops
@@ -757,16 +758,20 @@ class HuffmanCodec:
             padded[: raw.size] = raw
             words = padded.view(">u4").astype(np.uint32).view(np.int32)
             win = words[first[:, None] + np.arange(W)]
-            ids = ops.huffman_decode_op(
+            ids, steps = jax.device_get(ops.huffman_decode_op(
                 jnp.asarray(win), jnp.asarray(starts[:, None]),
                 jnp.asarray(counts[:, None]), jnp.asarray(dev["lut"]),
                 jnp.asarray(dev["cw_map"]), jnp.asarray(dev["order"]),
                 jnp.asarray(dev["len_sorted"]), chunk_size=chunk_size,
                 k=_LUT_BITS, n_ids=dev["n_ids"], use_pallas=True,
-                interpret=interpret)
+                interpret=interpret))
             # only the last selected chunk can be short, so row-major
             # flatten + truncate is exactly the symbol stream
-            flat_ids = np.asarray(ids).reshape(-1)[:n_symbols]
+            flat_ids = ids.reshape(-1)[:n_symbols]
+        # steps the probe's blocks ran, against the chunk_size each would
+        # run without stopping early
+        obs.count("gwlz.entropy.probe_steps", int(steps.sum()))
+        obs.count("gwlz.entropy.probe_steps_bound", chunk_size * steps.size)
         return self.alphabet[flat_ids]
 
     # -- serialization --------------------------------------------------------

@@ -90,15 +90,17 @@ def test_symbol_hist_compiles(one_chip, n_bins):
     assert _has_kernel(c)
 
 
-def test_huffman_decode_probe_compiles(one_chip):
-    """One 64^3 lane: 1024 chunk windows of 128 words, the 12-bit LUT, a
-    1024-symbol alphabet, six ids per probe."""
+@pytest.mark.parametrize("n_ids,n", [(6, 1024), (3, 512)])
+def test_huffman_decode_probe_compiles(one_chip, n_ids, n):
+    """One 64^3 lane: 1024 chunk windows of 128 words, the 12-bit LUT; a
+    1024-symbol alphabet with six ids per probe, and a 512-symbol one with
+    three (the dark-matter field's commonest table)."""
     from repro.kernels.huffman_decode import LUT_ROWS, huffman_decode_probe
 
     i32 = partial(_spec, one_chip, dtype=jnp.int32)
-    k, n = 12, 1024
+    k = 12
     c = _compile(lambda *a: huffman_decode_probe(*a, chunk_size=256, k=k,
-                                                 n_ids=6, interpret=False),
+                                                 n_ids=n_ids, interpret=False),
                  i32((1024, 128)), i32((1024, 1)), i32((1024, 1)),
                  i32((LUT_ROWS, 1 << k)), i32((1, n)), i32((1, n)), i32((1, n)))
     assert _has_kernel(c)

@@ -285,6 +285,74 @@ def test_device_range_decode_matches_host():
             got, decode_codes_range(blob, lo, hi, use_pallas=False))
 
 
+def _probe_steps(codes, cs, c0, c1):
+    """Steps the device probe runs on chunks [c0, c1) of ``codes``' stream:
+    the host walk over blocks of 8 of those chunks, each rounded up to a
+    whole check."""
+    import math
+
+    from repro.kernels.huffman_decode import CHECK_EVERY
+    from repro.sz import entropy
+
+    codec = HuffmanCodec.fit(codes)
+    stream, chunk_bits, _total = codec._device_pack(codes, cs, interpret=True)
+    offsets = np.cumsum(chunk_bits) - chunk_bits
+    counts = np.full(chunk_bits.size, cs, np.int64)
+    counts[-1] = codes.size - cs * (chunk_bits.size - 1)
+    offsets, counts = offsets[c0:c1], counts[c0:c1]
+    words, padded = entropy._sliding_words(stream, tail_pad=8 * cs + 16)
+    check = math.gcd(CHECK_EVERY, cs)
+    total = 0
+    for a in range(0, c1 - c0, 8):
+        walk = entropy._decode_lanes(words, padded, offsets[a:a + 8],
+                                     counts[a:a + 8],
+                                     np.empty((cs, min(8, c1 - c0 - a)), np.uint64),
+                                     codec._multi_tables())
+        total += -(-walk // check) * check
+    return total
+
+
+def test_device_decode_of_uneven_chunks_counts_probe_steps():
+    """A skewed stream whose chunks take very different probe step counts
+    (runs of the commonest symbol beside runs of rare, long codes): full and
+    range decode on the device == the host walk == the source codes, and the
+    probe counts the steps its blocks of 8 chunks ran, below chunk_size a
+    block."""
+    from repro import obs
+
+    cs = 64
+    rng = np.random.default_rng(31)
+    sizes = [2 ** i for i in range(16, 0, -1)] + [1, 1]
+    pool = np.repeat(np.arange(len(sizes), dtype=np.int32), sizes)
+    runs = [rng.choice(pool[-40:], cs), np.zeros(7 * cs, np.int32),
+            rng.choice(pool, 8 * cs), np.zeros(3 * cs, np.int32),
+            rng.choice(pool, 2 * cs + 7)]
+    codes = np.concatenate(runs + [pool[rng.permutation(pool.size)[:30]]])
+    blob = encode_codes(codes, "huffman+zlib", chunk_size=cs, use_pallas=False)
+    C = -(-codes.size // cs)
+    for lo, hi in [(0, codes.size), (cs + 1, cs + 2), (5 * cs - 3, 9 * cs + 2),
+                   (cs, codes.size - 5), (codes.size - 31, codes.size)]:
+        with obs.collect() as col:
+            got = decode_codes_range(blob, lo, hi, use_pallas=True)
+        np.testing.assert_array_equal(got, codes[lo:hi])
+        np.testing.assert_array_equal(
+            got, decode_codes_range(blob, lo, hi, use_pallas=False))
+        c0, c1 = lo // cs, -(-hi // cs)
+        n, _s, steps = col.stages()["gwlz.entropy.probe_steps"]
+        n_b, _s, bound = col.stages()["gwlz.entropy.probe_steps_bound"]
+        assert n == n_b == 1
+        assert bound == cs * -(-(c1 - c0) // 8)
+        assert steps == _probe_steps(codes, cs, c0, c1)
+        assert steps < bound, (steps, bound)
+    with obs.collect() as col:
+        got = decode_codes(blob, codes.shape, use_pallas=True)
+    np.testing.assert_array_equal(got, codes)
+    np.testing.assert_array_equal(got, decode_codes(blob, codes.shape,
+                                                    use_pallas=False))
+    _n, _s, steps = col.stages()["gwlz.entropy.probe_steps"]
+    assert steps == _probe_steps(codes, cs, 0, C)
+
+
 def test_device_host_fuzz_parity():
     """Seeded fuzz: random alphabets, skews, lengths, and chunk sizes — the
     device blob must stay bit-identical and decode must invert."""

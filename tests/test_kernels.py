@@ -290,11 +290,74 @@ def test_huffman_decode_matches_ref():
                                      dev["lut"], dev["cw_map"], dev["order"],
                                      dev["len_sorted"])]
     kw = dict(chunk_size=cs, k=_LUT_BITS, n_ids=dev["n_ids"])
-    ids_a = ops.huffman_decode_op(*args, **kw, use_pallas=True, interpret=True)
-    ids_b = ref.huffman_decode_ref(*args, **kw)
+    ids_a, steps_a = ops.huffman_decode_op(*args, **kw, use_pallas=True,
+                                           interpret=True)
+    ids_b, steps_b = ref.huffman_decode_ref(*args, **kw)
     np.testing.assert_array_equal(np.asarray(ids_a), np.asarray(ids_b))
+    np.testing.assert_array_equal(np.asarray(steps_a), np.asarray(steps_b))
     flat = np.asarray(ids_a).reshape(-1)[: codes.size]
     np.testing.assert_array_equal(codec.alphabet[flat], codes)
+
+
+def test_huffman_decode_blocks_stop_when_their_lanes_are_done():
+    """Blocks whose lanes need very different step counts: chunks of 1-bit
+    codes (several symbols a probe), chunks of codes longer than the LUT
+    (the escape, one symbol a probe), a short last chunk and padded lanes.
+    Pallas interpret == the jnp reference == the source codes, and each
+    block runs the steps its slowest lane needs on the host walk, rounded up
+    to a whole check."""
+    from repro.kernels import huffman_decode
+    from repro.sz import entropy
+
+    cs, bb = 64, 8
+    sizes = [2 ** i for i in range(18, 0, -1)] + [1, 1]  # lengths 1 .. 19
+    codec = entropy.HuffmanCodec.fit(
+        np.repeat(np.arange(len(sizes), dtype=np.int32), sizes))
+    long_syms = np.flatnonzero(codec.lengths > entropy._LUT_BITS)
+    assert codec.lengths[0] == 1 and long_syms.size, "needs 1-bit and escape codes"
+    rng = np.random.default_rng(5)
+    ones = np.zeros(cs, np.int32)
+    escapes = lambda: codec.alphabet[rng.choice(long_syms, cs)]
+    mixed = lambda: rng.choice(np.repeat(np.arange(8, dtype=np.int32), 3), cs)
+    chunks = ([ones, escapes()] + [ones] * 6  # block 0: one escape lane
+              + [ones] * 8  # block 1: every lane at several symbols a step
+              + [mixed(), escapes(), mixed()[:cs // 2 + 3]])  # block 2: 5 pads
+    codes = np.concatenate(chunks)
+    C = len(chunks)
+    stream, chunk_bits, _total = codec._device_pack(codes, cs, interpret=True)
+    dev = codec._device_tables()
+    offsets = (np.cumsum(chunk_bits) - chunk_bits).astype(np.int64)
+    counts = np.full(C, cs, np.int64)
+    counts[-1] = codes.size - cs * (C - 1)
+    W = 128  # a chunk of 64 19-bit codes spans 38 words
+    raw = np.frombuffer(stream, np.uint8)
+    padded = np.zeros(4 * (int(offsets.max() >> 5) + W), np.uint8)
+    padded[: raw.size] = raw
+    words = padded.view(">u4").astype(np.uint32).view(np.int32)
+    args = [jnp.asarray(a) for a in (
+        words[(offsets >> 5)[:, None] + np.arange(W)],
+        (offsets & 31).astype(np.int32)[:, None],
+        counts.astype(np.int32)[:, None], dev["lut"], dev["cw_map"],
+        dev["order"], dev["len_sorted"])]
+    kw = dict(chunk_size=cs, k=entropy._LUT_BITS, n_ids=dev["n_ids"])
+    ids_a, steps_a = ops.huffman_decode_op(*args, **kw, use_pallas=True,
+                                           interpret=True)
+    ids_b, steps_b = ref.huffman_decode_ref(*args, **kw, block_chunks=bb)
+    np.testing.assert_array_equal(np.asarray(ids_a), np.asarray(ids_b))
+    np.testing.assert_array_equal(np.asarray(steps_a), np.asarray(steps_b))
+    flat = np.asarray(ids_a).reshape(-1)[: codes.size]
+    np.testing.assert_array_equal(codec.alphabet[flat], codes)
+    # the host walk over a block's lanes runs until its slowest lane is
+    # done; the kernel checks for that once every CHECK_EVERY steps
+    hwords, hpad = entropy._sliding_words(stream, tail_pad=8 * cs + 16)
+    walk = np.array([
+        entropy._decode_lanes(hwords, hpad, offsets[a:a + bb], counts[a:a + bb],
+                              np.empty((cs, min(bb, C - a)), np.uint64),
+                              codec._multi_tables())
+        for a in range(0, C, bb)])
+    check = huffman_decode.CHECK_EVERY
+    np.testing.assert_array_equal(np.asarray(steps_a), -(-walk // check) * check)
+    assert walk[0] == cs and walk[1] < cs // 2, walk  # the blocks really differ
 
 
 def test_group_hist_matches_grouping_module():
